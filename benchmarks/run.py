@@ -46,6 +46,9 @@ def main() -> None:
     args = ap.parse_args()
     skip = set(args.skip.split(",")) if args.skip else set()
 
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks.fl_common import BenchScale
     if args.smoke:
         scale = BenchScale(samples_per_client=120, rounds=2,

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.core import Federation
 from repro.core.client import LocalSpec
 from repro.core.metrics import ccr
@@ -77,6 +78,7 @@ def main():
     ap.add_argument("--clients", type=int, default=3)
     ap.add_argument("--rounds", type=int, default=6)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # narrow vocab so the Markov table is learnable within the demo budget
     cfg = get_smoke_config(args.arch).replace(vocab_size=128)

@@ -26,6 +26,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.algorithms import available_algorithms
+from repro.common.compile_cache import enable_compile_cache
 from repro.core import Federation
 from repro.core.client import LocalSpec
 from repro.core.metrics import ccr
@@ -58,6 +59,7 @@ def main():
     ap.add_argument("--buffer", type=int, default=1,
                     help="batched engine FedBuff buffer size K")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.engine == "batched" and args.mode != "event":
         ap.error("--engine batched requires --mode event")
     if (args.buffer != 1 or args.max_batch) and args.engine != "batched":

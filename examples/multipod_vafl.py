@@ -44,6 +44,7 @@ def main():
     import jax
     import numpy as np
 
+    from repro.common.compile_cache import enable_compile_cache
     from repro.core import Federation
     from repro.core.client import LocalSpec
     from repro.data.partition import iid_partition
@@ -51,6 +52,7 @@ def main():
     from repro.models.cnn import MLPConfig, mlp_forward, mlp_init
     from repro.obs import ObsConfig
 
+    enable_compile_cache()
     print(f"devices: {jax.device_count()} placeholder pods, "
           f"{args.silos} silos")
     xtr, ytr, xte, yte = synthetic_mnist(

@@ -16,11 +16,14 @@ docs/ARCHITECTURE.md) and ``scenario=`` for any zoo name
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.core import Federation
 from repro.core.client import LocalSpec
 from repro.core.metrics import ccr
 from repro.data.partition import iid_partition
 from repro.data.synthetic import synthetic_mnist
+
+enable_compile_cache()
 
 # 1. data: synthetic MNIST, split IID across 3 clients
 xtr, ytr, xte, yte = synthetic_mnist(3000, 1000, seed=0)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.pytree import tree_bytes
 from repro.launch.steps import make_serve_step
 from repro.models import decoder
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--gen", type=int, default=12)
     ap.add_argument("--batch", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     for arch in ("starcoder2_3b", "minicpm3_4b", "rwkv6_3b"):
         cfg = get_smoke_config(arch)

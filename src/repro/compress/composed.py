@@ -15,7 +15,6 @@ actual kept count exactly.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -27,18 +26,13 @@ from repro.kernels.topk_quant import ops
 class TopKQuantCodec(Codec):
     """topk(frac) -> stochastic int8 on the values plane, fused.
 
-    interpret=None (the default) compiles the kernel on TPU and falls
-    back to Pallas interpret mode elsewhere (CPU CI), so the fused path
-    is actually compiled where the hardware supports it."""
+    The kernel runs compiled on TPU and in Pallas interpret mode on the
+    CPU backend (``repro.kernels.interpret_mode``)."""
 
-    def __init__(self, frac: float = 0.1, *, use_kernel: bool = True,
-                 interpret: bool = None):
+    def __init__(self, frac: float = 0.1, *, use_kernel: bool = True):
         assert 0.0 < frac <= 1.0, frac
         self.frac = frac
         self.use_kernel = use_kernel
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        self.interpret = interpret
         self.name = f"topk{frac:g}_int8"
 
     def encode(self, tree, *, seed: int = 0) -> Payload:
@@ -48,8 +42,7 @@ class TopKQuantCodec(Codec):
         k = max(1, int(round(self.frac * n)))
         thr, scale = ops.topk_threshold_scale(x2d, n, k)
         q, mask = ops.topk_quant(x2d, thr, scale, seed & 0xFFFFFFFF,
-                                 use_kernel=self.use_kernel,
-                                 interpret=self.interpret)
+                                 use_kernel=self.use_kernel)
         kept = np.flatnonzero(np.asarray(mask).ravel()).astype(np.int32)
         planes = {"idx": kept, "val": np.asarray(q).ravel()[kept]}
         meta = {"treedef": treedef, "shapes": shapes, "dtypes": dtypes,

@@ -72,8 +72,10 @@ class FLRunConfig:
     #     ("clients",) mesh over the host's devices (NamedSharding on the
     #     leading client axis) so each window's vmapped local update runs
     #     data-parallel across devices.  A 1-device mesh is bit-exact
-    #     with the unsharded engine; N must divide the device count's
-    #     multiple or the state silently stays replicated.
+    #     with the unsharded engine; the device count must divide N, or
+    #     the run raises ValueError.  The Pallas ``grad_diff_norm`` value
+    #     backend is refused with it: the compiler cannot partition the
+    #     kernel inside the value term over the sharded state.
     #   eval_subsample — evaluate the per-client Eq. 1 accuracy term on a
     #     deterministic random subset of this many test samples instead
     #     of the full test set (0 = full).  Applied by the Federation
@@ -134,6 +136,14 @@ class FLRunConfig:
         if self.eval_subsample < 0 or self.eval_cache < 0:
             raise ValueError("eval_subsample and eval_cache must be >= 0 "
                              f"(got {self.eval_subsample}, {self.eval_cache})")
+        if self.shard_clients and self.value_backend is not None:
+            from repro.kernels.grad_diff_norm import ops as gd_ops
+            if self.value_backend in (gd_ops.value_backend,
+                                      gd_ops.tree_grad_diff_sq_norm):
+                raise ValueError(
+                    "shard_clients=True cannot run the Pallas grad_diff_norm "
+                    "value_backend: the kernel cannot be partitioned over "
+                    "the sharded client state; drop one of the two")
         if self.checkpoint_every < 0:
             raise ValueError(
                 f"checkpoint_every must be >= 0 (got {self.checkpoint_every})")

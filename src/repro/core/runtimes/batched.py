@@ -135,9 +135,13 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
             "mask": jnp.asarray(fed_data.mask)}
 
     sharding = None
+    encode_on = None
     if run_cfg.shard_clients:
         from repro.distributed.sharding import client_state_sharding
         sharding = client_state_sharding(N)
+        # the codec's Pallas kernel cannot be partitioned over the mesh:
+        # each upload is encoded on one device
+        encode_on = sharding.mesh.devices.flat[0]
     ops = _engine_jits(sharding)
 
     # device-resident stacked per-client state: no Python lists of full
@@ -434,7 +438,8 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
                     recon = _compressed_upload(
                         codec, ef, comm, stacked_index(sub_base, r),
                         stacked_index(newp, r), i,
-                        _enc_seed(run_cfg, ev + j, i, _UPLOAD), obs=obs)
+                        _enc_seed(run_cfg, ev + j, i, _UPLOAD), obs=obs,
+                        device=encode_on)
                     buffer.append((jax.tree.map(lambda x: x[None], recon), 0))
                 staleness = server_version - model_version[i]
                 buf_stale.append(aggregator.stale_weight(staleness))

@@ -122,13 +122,18 @@ def _tree_apply_delta(base, delta):
 
 
 def _compressed_upload(codec, ef, comm, base, client_tree, i, seed,
-                       obs=None):
+                       obs=None, device=None):
     """One client's compressed upload: encode codec(delta vs ``base``, the
     model the client downloaded) with error feedback, account the wire
     bytes, and return the reconstruction the server actually receives.
+    ``device`` (sharded client state) moves the delta, and so the
+    client's error-feedback residual, onto that one device first: a
+    Pallas codec kernel cannot be partitioned across devices.
     Under obs the encode+decode is a host-timed "encode" span tagged
     with the codec and the payload's actual wire bytes."""
     delta = _tree_delta(client_tree, base)
+    if device is not None:
+        delta = jax.device_put(delta, device)
     with (obs.timed("encode", client=i, codec=codec.name)
           if obs is not None else nullcontext()):
         payload, decoded = compress_update(codec, ef, i, delta, seed=seed)
@@ -343,6 +348,46 @@ def _engine_jits(sharding):
         commit_full_flush=commit_full_flush, scatter_donated=scatter_donated)
 
 
+# clients whose accuracy scans run at once: the per-client evaluator
+# scans the whole test set, and vmapped over a window every scan step
+# holds rows x eval_batch model activations.  The CNN at eval_batch 500
+# needs ~64 MB of scratch per client (ahead-of-time compile for v5e), so
+# 64 rows take ~4.2 GB where a 256-client window at once took ~16.4 GB,
+# more than a v5e chip's 16 GB.
+_EVAL_ROWS = 64
+
+
+def _client_eval_vmap(client_eval_fn):
+    """``jax.vmap(client_eval_fn)`` over a stacked pytree, evaluating at
+    most ``_EVAL_ROWS`` clients at a time: a window of w > rows clients
+    runs as a scan of ceil(w / rows) steps.  Step s takes clients
+    a * steps + s, so under client sharding every step spreads over all
+    devices (the reshape keeps the leading axis's device blocks).
+    Padding rows (a window that ``rows`` does not divide) repeat client
+    0 and are dropped.  Used by the two helper sets below."""
+    rows = _EVAL_ROWS
+    # flcheck: ignore[jit-in-hot-path]
+    vf = jax.vmap(client_eval_fn)
+
+    def run(stack):
+        w = jax.tree.leaves(stack)[0].shape[0]
+        if w <= rows:
+            return vf(stack)
+        steps = -(-w // rows)
+        pad = steps * rows - w
+
+        def split(x):
+            if pad:
+                x = jnp.concatenate(
+                    [x, jnp.broadcast_to(x[:1], (pad,) + x.shape[1:])])
+            return x.reshape((rows, steps) + x.shape[1:]).swapaxes(0, 1)
+
+        out = jax.lax.map(vf, jax.tree.map(split, stack))
+        return jax.tree.map(
+            lambda y: y.swapaxes(0, 1).reshape((-1,) + y.shape[2:])[:w], out)
+    return run
+
+
 def _round_helpers(run_cfg, client_eval_fn):
     """Jitted stacked round inputs shared by the round-based and
     sync-barrier runtimes: per-client eval, Eq. 1 values, grad norms.
@@ -354,7 +399,7 @@ def _round_helpers(run_cfg, client_eval_fn):
     # this once per run and the closures capture run-specific N/sq_diff;
     # caching would pin the eval fn's device arrays past the run
     # flcheck: ignore[jit-in-hot-path]
-    batch_eval = jax.jit(jax.vmap(client_eval_fn))
+    batch_eval = jax.jit(_client_eval_vmap(client_eval_fn))
     # flcheck: ignore[jit-in-hot-path]
     values_fn = jax.jit(
         lambda gp, gc, accs: value_lib.communication_values_stacked(
@@ -390,7 +435,7 @@ def _build_event_helpers(num_clients, client_eval_fn, sq_diff):
     # lru_cache; the direct call is the documented unhashable-eval
     # fallback), so the zero-recompile-rerun contract holds
     # flcheck: ignore[jit-in-hot-path]
-    batch_eval = jax.jit(jax.vmap(client_eval_fn))
+    batch_eval = jax.jit(_client_eval_vmap(client_eval_fn))
     # flcheck: ignore[jit-in-hot-path]
     values_fn = jax.jit(jax.vmap(
         lambda pg, gc, a: value_lib.communication_value(

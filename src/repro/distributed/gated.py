@@ -21,28 +21,11 @@ Everything here runs inside ``shard_map`` over the "pod" mesh axis with
 """
 from __future__ import annotations
 
-import inspect
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.value import value_base
-
-# jax >= 0.6 exposes jax.shard_map and renames the replication-check kwarg
-# check_rep -> check_vma; older releases only have the experimental module.
-# Detect the kwarg by signature, not jax version: during the deprecation
-# window jax.shard_map is public but still takes check_rep.
-_raw_shard_map = getattr(jax, "shard_map", None)
-if _raw_shard_map is None:
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-try:
-    _kwarg = ("check_vma" if "check_vma"
-              in inspect.signature(_raw_shard_map).parameters else "check_rep")
-except (ValueError, TypeError):   # signature unavailable: assume modern name
-    _kwarg = "check_vma"
-_shard_map = partial(_raw_shard_map, **{_kwarg: False})
 
 
 def pod_values(grad_prev, grad_cur, acc, n_pods):
@@ -92,8 +75,8 @@ def make_gated_allreduce(mesh: Mesh, update_specs, axis_name: str = "pod"):
         agg, sel, any_sel = gated_psum(local, values[0], weights[0], axis_name)
         return agg, sel[None], any_sel
 
-    return jax.jit(_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs))
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def should_sync(values, axis_name: str = "pod"):

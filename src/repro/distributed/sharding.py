@@ -22,15 +22,11 @@ from repro.models.factory import is_abstract_leaf
 
 
 def make_mesh(axis_shapes, axis_names) -> Mesh:
-    """Version-portable jax.make_mesh: newer jax wants explicit Auto axis
-    types (manual-axes default changed); older jax (< 0.5) has no
-    jax.sharding.AxisType at all.  Single construction point so callers
-    and subprocess test snippets don't hard-code either API."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=tuple(jax.sharding.AxisType.Auto for _ in axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    the compiler), the one mesh construction point of the repo."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=tuple(jax.sharding.AxisType.Auto for _ in axis_names))
 
 
 # ------------------------------------------------- federated client axis ---
@@ -46,19 +42,21 @@ def client_mesh(num_devices: Optional[int] = None) -> Mesh:
 
 
 def client_state_sharding(num_clients: int,
-                          mesh: Optional[Mesh] = None) -> Optional[NamedSharding]:
+                          mesh: Optional[Mesh] = None) -> NamedSharding:
     """``NamedSharding`` for stacked per-client pytrees: dim0 over
     ``"clients"``, everything else replicated (``P("clients")`` names only
-    the leading dim).  Returns ``None`` — the replicated/single-host
-    fallback — when the client count does not divide the device count
-    (same divisibility policy as ``spec_for``: never a partial shard).
+    the leading dim).  Raises ``ValueError`` when the device count does
+    not divide the client count: asking for sharded client state and
+    silently getting a replicated run is never what the caller meant.
     A single-device mesh is a valid degenerate case: the constraint is a
     no-op there, which is what keeps the sharded engine bit-exact with
     the unsharded one (tests/test_async_engine.py)."""
     mesh = mesh if mesh is not None else client_mesh()
     ndev = int(mesh.devices.size)
     if num_clients % ndev:
-        return None
+        raise ValueError(
+            f"shard_clients: {num_clients} clients do not divide over "
+            f"{ndev} devices; choose num_clients as a multiple of {ndev}")
     return NamedSharding(mesh, P("clients"))
 
 # FSDP x TP: d_model dim sharded over data (ZeRO-style), ff/heads/vocab over

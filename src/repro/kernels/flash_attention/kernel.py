@@ -11,16 +11,19 @@ sliding window (for the SWA serve variant).
 This is the substrate kernel the model zoo's attention layers target on
 real TPUs; the XLA chunked path in models/attention.py is the lowering
 used for the CPU dry-run, and ref.py is the oracle both are tested
-against (interpret=True on CPU).
+against (interpret mode on the CPU backend).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -66,9 +69,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(jax.jit,
                    static_argnames=("bq", "bk", "window", "interpret"))
 def flash_attention(q, k, v, *, bq: int = 128, bk: int = 128, window=None,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q, k, v: (BH, S, D) (kv heads pre-broadcast to q heads).  Causal.
-    Returns (BH, S, D)."""
+    Returns (BH, S, D).  ``interpret`` resolves through
+    ``repro.kernels.interpret_mode``."""
     BH, S, D = q.shape
     assert S % bq == 0 and S % bk == 0, (S, bq, bk)
     scale = 1.0 / (D ** 0.5)
@@ -90,5 +94,5 @@ def flash_attention(q, k, v, *, bq: int = 128, bk: int = 128, window=None,
             pltpu.VMEM((bq, 1), jnp.float32),   # running denominator
             pltpu.VMEM((bq, D), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

@@ -27,17 +27,16 @@ def _flatten_pad(tree):
     return v.reshape(-1, LANE)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def tree_grad_diff_sq_norm(tree_a, tree_b, *, interpret: bool = True):
+@jax.jit
+def tree_grad_diff_sq_norm(tree_a, tree_b):
     a = _flatten_pad(tree_a)
     b = _flatten_pad(tree_b)
-    return grad_diff_sq_norm_2d(a, b, interpret=interpret)
+    return grad_diff_sq_norm_2d(a, b)
 
 
-@functools.partial(jax.jit, static_argnames=("n_clients", "interpret"))
-def communication_value(tree_a, tree_b, acc, n_clients: int, *,
-                        interpret: bool = True):
-    diff = tree_grad_diff_sq_norm(tree_a, tree_b, interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("n_clients",))
+def communication_value(tree_a, tree_b, acc, n_clients: int):
+    diff = tree_grad_diff_sq_norm(tree_a, tree_b)
     return diff * (1.0 + n_clients / 1e3) ** jnp.asarray(acc, jnp.float32)
 
 

@@ -20,11 +20,13 @@ kernel receives them as (1, 1) operands pinned to every grid step.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
 from repro.kernels.topk_quant import ref
 
 LANE = 128      # TPU lane width
@@ -52,10 +54,11 @@ def _kernel(x_ref, thr_ref, scale_ref, seed_ref, q_ref, mask_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def topk_quant_2d(x, thr, scale, seed, *, interpret: bool = True):
+def topk_quant_2d(x, thr, scale, seed, *, interpret: Optional[bool] = None):
     """x: (M, 128) fp32, M % TILE_M == 0; thr/scale fp32 scalars; seed
     uint32 scalar.  Returns (q int8, mask int8), both (M, 128).
-    (ops.py handles pytree flattening/padding and the scalar prologue.)"""
+    (ops.py handles pytree flattening/padding and the scalar prologue.)
+    ``interpret`` resolves through ``repro.kernels.interpret_mode``."""
     m = x.shape[0]
     grid = (m // TILE_M,)
     scalar = lambda v, dt: jnp.asarray(v, dt).reshape(1, 1)
@@ -75,6 +78,6 @@ def topk_quant_2d(x, thr, scale, seed, *, interpret: bool = True):
             jax.ShapeDtypeStruct((m, LANE), jnp.int8),
             jax.ShapeDtypeStruct((m, LANE), jnp.int8),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x.astype(jnp.float32), scalar(thr, jnp.float32),
       scalar(scale, jnp.float32), scalar(seed, jnp.uint32))

@@ -43,13 +43,12 @@ def topk_threshold_scale(x2d, n, k: int):
     return thr, scale
 
 
-def topk_quant(x2d, thr, scale, seed, *, use_kernel: bool = True,
-               interpret: bool = True):
+def topk_quant(x2d, thr, scale, seed, *, use_kernel: bool = True):
     """Fused select+quantize over the packed buffer -> (q int8, mask int8).
     use_kernel=False routes through the pure-jnp oracle (identical bits)."""
     # normalize before the jit boundary: a Python int above 2^31 would
     # otherwise be abstracted as int32 and overflow
     seed = jnp.asarray(seed, jnp.uint32)
     if use_kernel:
-        return topk_quant_2d(x2d, thr, scale, seed, interpret=interpret)
+        return topk_quant_2d(x2d, thr, scale, seed)
     return ref.topk_quant_2d(x2d, jnp.float32(thr), jnp.float32(scale), seed)

@@ -1,7 +1,7 @@
 """Pure-jnp oracle for the topk_quant kernel.
 
-Semantics (shared spec with kernel.py — the two must match bit-for-bit in
-interpret mode):
+Semantics (shared spec with kernel.py — the two must match bit-for-bit,
+compiled on TPU or in interpret mode):
 
   keep = |x| >= thr
   q    = clip(floor(clip(x / scale, -127, 127) + u), -127, 127)  where kept
@@ -32,7 +32,10 @@ def hash_uniform(idx, seed):
     x = x ^ (x >> jnp.uint32(15))
     x = x * jnp.uint32(0x846CA68B)
     x = x ^ (x >> jnp.uint32(16))
-    return x.astype(jnp.float32) * jnp.float32(2.0 ** -32)
+    # top 24 bits -> [0, 1): exact in fp32, and uint32 -> fp32 is not a
+    # cast the TPU kernel compiler supports, int32 -> fp32 is
+    return ((x >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(2.0 ** -24))
 
 
 def topk_quant_2d(x, thr, scale, seed):

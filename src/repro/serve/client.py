@@ -28,6 +28,7 @@ can be cross-checked.
 from __future__ import annotations
 
 import multiprocessing
+import os
 import threading
 import time
 from types import SimpleNamespace
@@ -455,7 +456,12 @@ def _process_client_main(host, port, client, forward_fn, model_cfg, local,
                          images, labels, mask, rounds, pace_seed):
     """Entry point of a spawned client process (module-level so the
     spawn pickler can import it).  Rebuilds the compute bundle from
-    numpy inputs; single-phase algorithms only (no eval set here)."""
+    numpy inputs; single-phase algorithms only (no eval set here).
+    Runs on the CPU backend: the server process holds the accelerator
+    (``ProcessClientWorker.start``)."""
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(f"process client {client} runs on the CPU "
+                           f"backend, got {jax.default_backend()!r}")
     from repro.core.client import make_weighted_classifier_loss
     loss_fn = make_weighted_classifier_loss(forward_fn, model_cfg)
     compute = ClientCompute(
@@ -472,12 +478,19 @@ def _process_client_main(host, port, client, forward_fn, model_cfg, local,
                  rounds=rounds)
 
 
+# serializes the environment swap in ProcessClientWorker.start
+_SPAWN_ENV_LOCK = threading.Lock()
+
+
 class ProcessClientWorker:
     """One client as an OS process over the ``socket`` transport.  The
     child rebuilds its jits from picklable pieces (forward fn by module
     reference, model/local dataclasses, its own data rows as numpy) —
     so only registry-style models travel; single-phase algorithms only
-    (the Eq. 1 value term needs the server's eval set)."""
+    (the Eq. 1 value term needs the server's eval set).
+
+    The child is a simulated edge device and runs on the CPU backend:
+    an accelerator belongs to one process, and the server holds it."""
 
     def __init__(self, address, client: int, *, forward_fn, model_cfg,
                  local, fed_data, rounds: Optional[int] = None,
@@ -495,7 +508,21 @@ class ProcessClientWorker:
         self.client = client
 
     def start(self) -> None:
-        self._proc.start()
+        """Spawn the child with ``JAX_PLATFORMS=cpu`` in its environment.
+        A spawned child inherits the parent's environment when it is
+        exec'd, and it imports jax (unpickling its target and args)
+        before any code of ours runs there — so the variable is set in
+        the parent for the duration of the spawn."""
+        with _SPAWN_ENV_LOCK:
+            old = os.environ.get("JAX_PLATFORMS")
+            os.environ["JAX_PLATFORMS"] = "cpu"
+            try:
+                self._proc.start()
+            finally:
+                if old is None:
+                    del os.environ["JAX_PLATFORMS"]
+                else:
+                    os.environ["JAX_PLATFORMS"] = old
 
     def join(self, timeout: Optional[float] = None) -> None:
         self._proc.join(timeout)

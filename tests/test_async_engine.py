@@ -290,6 +290,72 @@ class TestEngineEquivalence:
         assert out.returncode == 0, out.stderr[-2000:]
         assert "OK" in out.stdout
 
+    def test_multi_device_indivisible_clients_raise(self):
+        """shard_clients=True with a client count the device count does
+        not divide is refused (4 forced CPU devices, N=6) — by the
+        sharding helper and by a run — instead of going on replicated."""
+        import subprocess
+        import sys
+        import textwrap
+        code = textwrap.dedent("""
+            import os
+            os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+            import sys; sys.path.insert(0, "src")
+            import jax
+            from repro.core import FLRunConfig, run_event_driven
+            from repro.core.client import LocalSpec, make_weighted_classifier_loss
+            from repro.data.partition import iid_partition
+            from repro.data.synthetic import synthetic_mnist
+            from repro.distributed.sharding import client_state_sharding
+            from repro.models.cnn import MLPConfig, mlp_forward, mlp_init
+
+            assert jax.device_count() == 4
+            assert client_state_sharding(8) is not None
+            try:
+                client_state_sharding(6)
+            except ValueError as e:
+                assert "6 clients" in str(e), e
+            else:
+                raise SystemExit("client_state_sharding(6) did not raise")
+
+            xtr, ytr, xte, yte = synthetic_mnist(6 * 40, 40, seed=0)
+            mcfg = MLPConfig(hidden=(8,))
+            fed = iid_partition(xtr, ytr, 6, samples_per_client=40, seed=0)
+            rc = FLRunConfig(algorithm="afl", num_clients=6, rounds=1,
+                             local=LocalSpec(batch_size=20, local_rounds=1,
+                                             lr=0.1),
+                             engine="batched", shard_clients=True)
+            try:
+                run_event_driven(
+                    rc, init_params_fn=lambda k: mlp_init(mcfg, k),
+                    loss_fn=make_weighted_classifier_loss(mlp_forward, mcfg),
+                    fed_data=fed, evaluate_fn=lambda p: 0.0)
+            except ValueError as e:
+                assert "do not divide over 4 devices" in str(e), e
+            else:
+                raise SystemExit("shard_clients run with N=6 did not raise")
+            print("OK")
+        """)
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True, cwd=".")
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert "OK" in out.stdout
+
+
+    @pytest.mark.parametrize("backend", ["value_backend",
+                                         "tree_grad_diff_sq_norm"])
+    def test_shard_clients_refuses_pallas_value_backend(self, backend):
+        """The compiler cannot partition the grad_diff_norm kernel inside
+        the vmapped value term over sharded client state, so the config
+        refuses the pair loudly; each alone is accepted."""
+        from repro.kernels.grad_diff_norm import ops as gd_ops
+        fn = getattr(gd_ops, backend)
+        with pytest.raises(ValueError, match="grad_diff_norm"):
+            FLRunConfig(algorithm="vafl", engine="batched",
+                        shard_clients=True, value_backend=fn)
+        FLRunConfig(algorithm="vafl", engine="batched", value_backend=fn)
+        FLRunConfig(algorithm="vafl", engine="batched", shard_clients=True,
+                    value_backend=lambda a, b: 0.0)
 
 # -------------------------------------------------- buffered aggregation ---
 
@@ -381,6 +447,25 @@ class TestBatchedEngineScale:
 # ----------------------------------------------------- eval fast path ---
 
 class TestEvalFastPath:
+    @pytest.mark.parametrize("w", [1, 64, 65, 200, 256])
+    def test_window_eval_in_client_chunks_matches_vmap(self, w):
+        """Windows wider than the 64-client chunk evaluate a chunk of
+        clients per scan step (strided rows, padded when the chunk does
+        not divide the window); every client's result equals the plain
+        vmap's."""
+        from repro.core.runtimes.common import _client_eval_vmap
+
+        def acc(p):
+            return jnp.tanh(p["w"] @ p["x"]).sum() + p["b"][0]
+
+        k = jax.random.split(jax.random.key(w), 3)
+        stack = {"w": jax.random.normal(k[0], (w, 6, 5)),
+                 "x": jax.random.normal(k[1], (w, 5)),
+                 "b": jax.random.normal(k[2], (w, 2))}
+        got = jax.jit(_client_eval_vmap(acc))(stack)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(jax.vmap(acc)(stack)))
+
     def test_subsampled_evaluator_deterministic(self, setup):
         """Same subsample seed -> the same test subset -> identical
         scores; a subsample covering the whole set is the full evaluator."""

@@ -233,6 +233,55 @@ class TestTopkQuantKernel:
             acc += np.asarray(q, np.float64) * 0.1
         np.testing.assert_allclose(acc / n_seeds, 0.3, atol=0.02)
 
+    def test_multi_device_operand_runs_on_one_device(self):
+        """A Pallas kernel cannot be partitioned across devices, so the
+        batched engine takes each upload out of client state sharded
+        over devices onto one device before the codec's kernel runs on
+        it.  4 forced CPU devices, in a subprocess."""
+        import subprocess
+        import sys
+        import textwrap
+        code = textwrap.dedent("""
+            import os
+            os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+            import sys; sys.path.insert(0, "src")
+            import jax
+            from repro.core import FLRunConfig, run_event_driven
+            from repro.core.client import LocalSpec, make_weighted_classifier_loss
+            from repro.data.partition import iid_partition
+            from repro.data.synthetic import synthetic_mnist
+            from repro.kernels.topk_quant import ops
+            from repro.models.cnn import MLPConfig, mlp_forward, mlp_init
+
+            spans = []
+            real = ops.topk_quant
+
+            def spy(x2d, *a, **k):
+                spans.append(len(x2d.sharding.device_set))
+                return real(x2d, *a, **k)
+            ops.topk_quant = spy
+
+            xtr, ytr, xte, yte = synthetic_mnist(8 * 40, 40, seed=0)
+            mcfg = MLPConfig(hidden=(8,))
+            fed = iid_partition(xtr, ytr, 8, samples_per_client=40, seed=0)
+            rc = FLRunConfig(algorithm="afl", num_clients=8, rounds=1,
+                             local=LocalSpec(batch_size=20, local_rounds=1,
+                                             lr=0.1),
+                             compressor="topk0.1_int8", engine="batched",
+                             shard_clients=True)
+            res = run_event_driven(
+                rc, init_params_fn=lambda k: mlp_init(mcfg, k),
+                loss_fn=make_weighted_classifier_loss(mlp_forward, mcfg),
+                fed_data=fed, evaluate_fn=lambda p: 0.0)
+            assert res.comm.model_uploads == 8, res.comm.model_uploads
+            assert spans == [1] * 8, spans
+            print("OK")
+        """)
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True, cwd=".")
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert "OK" in out.stdout
+
     def test_codec_kernel_and_oracle_paths_agree(self):
         tree = make_tree()
         pk = TopKQuantCodec(0.1, use_kernel=True).encode(tree, seed=11)

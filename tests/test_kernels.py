@@ -137,3 +137,23 @@ def test_linear_scan_layer_wrapper_matches_model_recurrence():
     want, _ = linear_recurrence(q, k, v, la, chunk=32, decay_per="dim")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4,
                                atol=2e-4)
+
+
+# ------------------------------------------------------- interpret rule ---
+
+@pytest.mark.parametrize("backend,requested,expected", [
+    ("cpu", None, True), ("cpu", False, False), ("cpu", True, True),
+    ("tpu", None, False), ("tpu", False, False), ("tpu", True, ValueError),
+    ("gpu", None, False),
+])
+def test_interpret_mode_rule(monkeypatch, backend, requested, expected):
+    """One rule for every kernel: compiled unless the backend is the CPU,
+    an explicit False always compiles (AOT for a described chip), and
+    interpret mode is refused on a TPU backend."""
+    from repro.kernels import interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if expected is ValueError:
+        with pytest.raises(ValueError, match="TPU backend"):
+            interpret_mode(requested)
+    else:
+        assert interpret_mode(requested) is expected

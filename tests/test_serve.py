@@ -12,6 +12,7 @@ reconcile exactly against ``CommStats``, plus multi-tenant interleaving.
 The determinism bridge (sequential serve == closed-loop engine, bit for
 bit) lives with the other golden-parity tests in test_algorithms.py.
 """
+import os
 import threading
 import time
 
@@ -325,6 +326,39 @@ class TestServerLifecycle:
         assert 1 <= server.processed < server.total_events
         assert res.comm.model_uploads == server.processed
         tr.close()
+
+    @pytest.mark.parametrize("parent_platforms", [None, "tpu"])
+    def test_process_worker_child_runs_on_cpu(self, setup, tmp_path,
+                                              monkeypatch, parent_platforms):
+        """The server process holds the accelerator, so a spawned client
+        must come up with JAX_PLATFORMS=cpu whatever the parent's
+        environment says — and the parent's environment is left as it
+        was.  The worker's child target is swapped for one that records
+        the environment it was started with."""
+        from repro.serve import ProcessClientWorker
+        mcfg, loss_fn, evaluate, fed = setup
+        if parent_platforms is None:
+            monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        else:
+            monkeypatch.setenv("JAX_PLATFORMS", parent_platforms)
+        out = tmp_path / "child_env.txt"
+        worker = ProcessClientWorker(
+            ("127.0.0.1", 0), 0, forward_fn=mlp_forward, model_cfg=mcfg,
+            local=_cfg().local, fed_data=fed)
+        worker._proc._target = _record_child_platforms
+        worker._proc._args = (str(out),)
+        worker.start()
+        assert os.environ.get("JAX_PLATFORMS") == parent_platforms
+        worker.join(timeout=120)
+        assert worker.exitcode == 0
+        assert out.read_text() == "cpu"
+
+
+def _record_child_platforms(path):
+    """Spawn target of the process-worker test (module level so the
+    spawned child can import it)."""
+    with open(path, "w") as f:
+        f.write(os.environ.get("JAX_PLATFORMS", "<unset>"))
 
 
 # ------------------------------------------------------ live federations ---

@@ -66,7 +66,8 @@ from repro.core.runtimes.common import (_BROADCAST, _UPLOAD,
                                         _compressed_upload, _enc_seed,
                                         _engine_jits, _event_helpers,
                                         _finish_obs, _make_codecs,
-                                        _obs_for_run, _tree_delta, _value_fn)
+                                        _obs_for_run, _annotate, _span,
+                                        _tree_delta, _value_fn)
 from repro.core.scheduler import EventScheduler
 from repro.obs.console import progress
 
@@ -120,74 +121,152 @@ class _AccCache:
 def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
                        fed_data, evaluate_fn, client_eval_fn, speed,
                        net=None, avail=None, verbose=False) -> RunResult:
-    N = run_cfg.num_clients
-    rng = jax.random.key(run_cfg.seed)
-    rng, krng = jax.random.split(rng)
-    global_params = init_params_fn(krng)
-    comm = CommStats(model_bytes=tree_bytes(global_params))
-    codec, bcodec, ef = _make_codecs(run_cfg)
-    sq_diff = _value_fn(run_cfg)
-
-    local_update = make_local_update(loss_fn, run_cfg.local)
-    keyed_update = make_local_update_keyed(loss_fn, run_cfg.local)
-    data = {"images": jnp.asarray(fed_data.images),
-            "labels": jnp.asarray(fed_data.labels),
-            "mask": jnp.asarray(fed_data.mask)}
-
-    sharding = None
-    encode_on = None
-    if run_cfg.shard_clients:
-        from repro.distributed.sharding import client_state_sharding
-        sharding = client_state_sharding(N)
-        # the codec's Pallas kernel cannot be partitioned over the mesh:
-        # each upload is encoded on one device
-        encode_on = sharding.mesh.devices.flat[0]
-    ops = _engine_jits(sharding)
-
-    # device-resident stacked per-client state: no Python lists of full
-    # pytrees, everything gathers/scatters on a leading axis (sharded on
-    # the ("clients",) mesh when configured)
-    client_params = jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (N,) + x.shape), global_params)
-    prev_grads = jax.tree.map(
-        lambda x: jnp.zeros((N,) + x.shape, jnp.float32), global_params)
-    if sharding is not None:
-        client_params = tree_shard(client_params, sharding)
-        prev_grads = tree_shard(prev_grads, sharding)
-        data = tree_shard(data, sharding)
-    model_version = np.zeros(N, int)  # version each client last downloaded
-    server_version = 0
-    prev_global = global_params
-    prev_prev_global = global_params
-
+    # the observer comes first: its host spans (run.start, each window and
+    # its phases, run.finish) cover the whole run, so that a profiler
+    # trace can put every stretch of device idle time down to one of them
     obs = _obs_for_run(run_cfg)
-    batch_eval, values_fn, norms_fn = _event_helpers(
-        run_cfg, client_eval_fn, sq_diff)
-    acc_cache = (_AccCache(N, run_cfg.eval_cache, batch_eval, ops.gather,
-                           obs=obs)
-                 if policy.needs_values and run_cfg.eval_cache > 0 else None)
-    # a window's final flush folds into the commit only when the default
-    # flush math applies (a plugin aggregator's override must stay in
-    # charge of its own mixing)
-    foldable_flush = type(aggregator).flush_mix is Aggregator.flush_mix
-
+    if obs is not None:                # opt-in device profiler (whole run)
+        obs.profile_start()
+    N = run_cfg.num_clients
     W = run_cfg.max_batch if run_cfg.max_batch > 0 else N
     W = max(1, min(W, N))
     K = max(1, run_cfg.buffer_size)
     total_events = run_cfg.rounds * N
-    sched = EventScheduler(N, speed, network=net, availability=avail,
-                           obs=obs)
-    # a reactive scenario consumes per-event payload bytes (or
-    # availability draws) at reschedule time, so the pipeline's
-    # reschedule+pop-ahead must wait for the window's upload decisions
-    reactive = sched.reactive
-    records: list = []
     # the FedBuff buffer: (stacked_tree, row) references — rows of the
     # window's vmapped output for identity uploads (client ids on the
     # fast path, window positions otherwise), size-1 stacks for codec
     # reconstructions; gathered/stacked only at flush time
     buffer: list = []
     buf_stale: list = []              # their staleness weights s(tau)
+    records: list = []
+    last_eval = (None, None)           # (server_version, acc device scalar)
+    ev = 0
+    pre_d = None                       # next window's pre-dispatched data
+    nxt = None
+
+    with _span(obs, "run.start"):
+        rng = jax.random.key(run_cfg.seed)
+        rng, krng = jax.random.split(rng)
+        global_params = init_params_fn(krng)
+        comm = CommStats(model_bytes=tree_bytes(global_params))
+        codec, bcodec, ef = _make_codecs(run_cfg)
+        sq_diff = _value_fn(run_cfg)
+
+        local_update = make_local_update(loss_fn, run_cfg.local)
+        keyed_update = make_local_update_keyed(loss_fn, run_cfg.local)
+        data = {"images": jnp.asarray(fed_data.images),
+                "labels": jnp.asarray(fed_data.labels),
+                "mask": jnp.asarray(fed_data.mask)}
+
+        sharding = None
+        encode_on = None
+        if run_cfg.shard_clients:
+            from repro.distributed.sharding import client_state_sharding
+            sharding = client_state_sharding(N)
+            # the codec's Pallas kernel cannot be partitioned over the
+            # mesh: each upload is encoded on one device
+            encode_on = sharding.mesh.devices.flat[0]
+        ops = _engine_jits(sharding)
+
+        # device-resident stacked per-client state: no Python lists of
+        # full pytrees, everything gathers/scatters on a leading axis
+        # (sharded on the ("clients",) mesh when configured)
+        client_params = jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (N,) + x.shape), global_params)
+        prev_grads = jax.tree.map(
+            lambda x: jnp.zeros((N,) + x.shape, jnp.float32), global_params)
+        if sharding is not None:
+            client_params = tree_shard(client_params, sharding)
+            prev_grads = tree_shard(prev_grads, sharding)
+            data = tree_shard(data, sharding)
+        model_version = np.zeros(N, int)  # version each client downloaded
+        server_version = 0
+        prev_global = global_params
+        prev_prev_global = global_params
+
+        batch_eval, values_fn, norms_fn = _event_helpers(
+            run_cfg, client_eval_fn, sq_diff)
+        acc_cache = (_AccCache(N, run_cfg.eval_cache, batch_eval,
+                               ops.gather, obs=obs)
+                     if policy.needs_values and run_cfg.eval_cache > 0
+                     else None)
+        # a window's final flush folds into the commit only when the
+        # default flush math applies (a plugin aggregator's override must
+        # stay in charge of its own mixing)
+        foldable_flush = type(aggregator).flush_mix is Aggregator.flush_mix
+
+        sched = EventScheduler(N, speed, network=net, availability=avail,
+                               obs=obs)
+        # a reactive scenario consumes per-event payload bytes (or
+        # availability draws) at reschedule time, so the pipeline's
+        # reschedule+pop-ahead must wait for the window's upload decisions
+        reactive = sched.reactive
+
+        # full-run checkpoint-resume (docs/RESILIENCE.md).  The pipeline
+        # is one window deep, so a checkpoint taken at the end of a loop
+        # body must bundle the already-popped NEXT window alongside the
+        # scheduler snapshot; buffered updates are materialized to host
+        # trees (their stacked-window sources don't outlive the iteration)
+        # and restored as size-1 stacks — exactly how codec
+        # reconstructions enter the buffer, so the flush math is
+        # unchanged.
+        ckpt_path, ckpt_every = (run_cfg.checkpoint_path,
+                                 run_cfg.checkpoint_every)
+        fingerprint = (ck.run_fingerprint(run_cfg, "batched", global_params)
+                       if ckpt_path else None)
+
+        if run_cfg.resume and ckpt_path and os.path.exists(ckpt_path):
+            st = ck.load_run_state(ckpt_path, fingerprint)
+            ev = int(st["event"])
+            rng = jax.random.wrap_key_data(jnp.asarray(st["rng"]))
+            global_params = ck.tree_to_device(st["global_params"])
+            prev_global = ck.tree_to_device(st["prev_global"])
+            prev_prev_global = ck.tree_to_device(st["prev_prev_global"])
+            client_params = ck.tree_to_device(st["client_params"])
+            prev_grads = ck.tree_to_device(st["prev_grads"])
+            if sharding is not None:
+                client_params = tree_shard(client_params, sharding)
+                prev_grads = tree_shard(prev_grads, sharding)
+            model_version = np.asarray(st["model_version"], int).copy()
+            server_version = int(st["server_version"])
+            comm.__dict__.update(st["comm"])
+            records = list(st["records"])
+            if st["last_eval"] is not None:
+                last_eval = (int(st["last_eval"][0]), st["last_eval"][1])
+            buffer = [(jax.tree.map(lambda x: x[None],
+                                    ck.tree_to_device(t)), 0)
+                      for t in st["buffer"]]
+            buf_stale = list(st["buf_stale"])
+            if st["policy"] is not None:
+                policy.set_state(st["policy"])
+            ef.residuals = {int(c): ck.tree_to_device(t)
+                            for c, t in st["ef"].items()}
+            if acc_cache is not None and st["acc_cache"] is not None:
+                acc_cache.acc = np.asarray(st["acc_cache"]["acc"],
+                                           np.float32).copy()
+                acc_cache.age = np.asarray(st["acc_cache"]["age"],
+                                           np.int64).copy()
+            sched.restore(st["sched"])
+            if st["nxt"] is not None:
+                times = np.asarray(st["nxt"][0], np.float64)
+                idx_np = np.asarray(st["nxt"][1], np.int64)
+            elif ev < total_events:
+                # the writer's event budget ended at this checkpoint, so
+                # it never popped a next window; a resume that EXTENDS the
+                # run (rounds is outside the fingerprint) pops it now —
+                # the restored scheduler is exactly the state the longer
+                # run popped from mid-body
+                times, idx_np = sched.pop_window(min(W, total_events - ev))
+            else:
+                times, idx_np = np.empty(0), np.empty(0, int)
+            if obs is not None:
+                if st.get("obs_metrics"):
+                    obs.metrics.restore(st["obs_metrics"])
+                obs.checkpoint(ev, obs.host_now(), restored=True)
+        else:
+            times, idx_np = (sched.pop_window(min(W, total_events))
+                             if total_events
+                             else (np.empty(0), np.empty(0, int)))
 
     def flush(sim=None):
         nonlocal global_params, prev_global, prev_prev_global, server_version
@@ -222,22 +301,6 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
         server_version += 1
         buffer.clear()
         buf_stale.clear()
-
-    last_eval = (None, None)           # (server_version, acc device scalar)
-    ev = 0
-    pre_d = None                       # next window's pre-dispatched data
-    nxt = None
-
-    # full-run checkpoint-resume (docs/RESILIENCE.md).  The pipeline is
-    # one window deep, so a checkpoint taken at the end of a loop body
-    # must bundle the already-popped NEXT window alongside the scheduler
-    # snapshot; buffered updates are materialized to host trees (their
-    # stacked-window sources don't outlive the iteration) and restored
-    # as size-1 stacks — exactly how codec reconstructions enter the
-    # buffer, so the flush math is unchanged.
-    ckpt_path, ckpt_every = run_cfg.checkpoint_path, run_cfg.checkpoint_every
-    fingerprint = (ck.run_fingerprint(run_cfg, "batched", global_params)
-                   if ckpt_path else None)
 
     def _save_ckpt():
         h0 = obs.host_now() if obs is not None else 0.0
@@ -276,301 +339,287 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
         if obs is not None:
             obs.checkpoint(ev, h0)
 
-    if run_cfg.resume and ckpt_path and os.path.exists(ckpt_path):
-        st = ck.load_run_state(ckpt_path, fingerprint)
-        ev = int(st["event"])
-        rng = jax.random.wrap_key_data(jnp.asarray(st["rng"]))
-        global_params = ck.tree_to_device(st["global_params"])
-        prev_global = ck.tree_to_device(st["prev_global"])
-        prev_prev_global = ck.tree_to_device(st["prev_prev_global"])
-        client_params = ck.tree_to_device(st["client_params"])
-        prev_grads = ck.tree_to_device(st["prev_grads"])
-        if sharding is not None:
-            client_params = tree_shard(client_params, sharding)
-            prev_grads = tree_shard(prev_grads, sharding)
-        model_version = np.asarray(st["model_version"], int).copy()
-        server_version = int(st["server_version"])
-        comm.__dict__.update(st["comm"])
-        records = list(st["records"])
-        if st["last_eval"] is not None:
-            last_eval = (int(st["last_eval"][0]), st["last_eval"][1])
-        buffer = [(jax.tree.map(lambda x: x[None], ck.tree_to_device(t)), 0)
-                  for t in st["buffer"]]
-        buf_stale = list(st["buf_stale"])
-        if st["policy"] is not None:
-            policy.set_state(st["policy"])
-        ef.residuals = {int(c): ck.tree_to_device(t)
-                        for c, t in st["ef"].items()}
-        if acc_cache is not None and st["acc_cache"] is not None:
-            acc_cache.acc = np.asarray(st["acc_cache"]["acc"],
-                                       np.float32).copy()
-            acc_cache.age = np.asarray(st["acc_cache"]["age"],
-                                       np.int64).copy()
-        sched.restore(st["sched"])
-        if st["nxt"] is not None:
-            times = np.asarray(st["nxt"][0], np.float64)
-            idx_np = np.asarray(st["nxt"][1], np.int64)
-        elif ev < total_events:
-            # the writer's event budget ended at this checkpoint, so it
-            # never popped a next window; a resume that EXTENDS the run
-            # (rounds is outside the fingerprint) pops it now — the
-            # restored scheduler is exactly the state the longer run
-            # popped from mid-body
-            times, idx_np = sched.pop_window(min(W, total_events - ev))
-        else:
-            times, idx_np = np.empty(0), np.empty(0, int)
-        if obs is not None:
-            if st.get("obs_metrics"):
-                obs.metrics.restore(st["obs_metrics"])
-            obs.checkpoint(ev, obs.host_now(), restored=True)
-    else:
-        times, idx_np = (sched.pop_window(min(W, total_events))
-                         if total_events else (np.empty(0), np.empty(0, int)))
-    if obs is not None:                # opt-in device profiler (hot loop)
-        obs.profile_start()
+    if obs is not None:
         obs.sampler_start()            # opt-in live metric sampler
     while len(idx_np):
         t_now = float(times[-1])
         w = len(idx_np)
         full = w == N                  # a full window = client permutation
-        h0 = obs.host_now() if obs is not None else 0.0
-        rng, urng = jax.random.split(rng)
+        # the window's record (written at the commit) and its host phases
+        with _annotate(obs, "window", size=w):
+            h0 = obs.host_now() if obs is not None else 0.0
 
-        # ---- dispatch the window's device work ------------------------
-        if full:
-            # run in client order with keys permuted to arrival positions:
-            # bit-exact with the gathered path, but the three O(N*|params|)
-            # stack copies (gather, prev-grad scatter, download scatter)
-            # vanish.  row(client i) == i.
-            inv = np.empty(N, np.int64)
-            inv[idx_np] = np.arange(N)
-            keys = jax.random.split(urng, N)[jnp.asarray(inv)]
-            sub_base = client_params
-            newp, eff, _ = keyed_update(client_params, data, keys)
-            row_of = idx_np            # event j -> row in newp/eff
-        else:
-            idx = jnp.asarray(idx_np)
-            sub_base = ops.gather(client_params, idx)
-            d_w = pre_d if pre_d is not None else ops.gather(data, idx)
-            newp, eff, _ = local_update(sub_base, d_w, urng)
-            row_of = np.arange(w)
-        pre_d = None
-        if obs is not None:
-            # host_dur here is DISPATCH time (XLA execution is async);
-            # the window span measures dispatch-through-commit
-            obs.local_update(float(times[0]), t_now, h0, clients=w)
-
-        # the policy's declared stacked inputs: ONE vmapped dispatch per
-        # window each, with the device->host copy started immediately so
-        # the host can keep dispatching while it lands
-        V_dev = norms_dev = None
-        if policy.needs_values:
-            if acc_cache is not None:
-                # rows of newp map to clients: identity on the fast path
-                # (client order), the window's arrival ids otherwise
-                accs = acc_cache.window_accs(
-                    newp, np.arange(N) if full else idx_np)
-            else:
-                accs = batch_eval(newp)
-            pg_w = prev_grads if full else ops.gather(prev_grads,
-                                                      jnp.asarray(idx_np))
-            V_dev = _host_async(values_fn(pg_w, eff, accs))
-        if policy.needs_norms:
-            norms_dev = _host_async(norms_fn(eff))
-
-        # ---- the one-window-deep pipeline ----------------------------
-        # everything gating CANNOT change happens before we block on the
-        # gating inputs: restart each client from its own completion time
-        # (window execution must not barrier the simulated clock), pop
-        # the NEXT window, and pre-dispatch its data gather.  A reactive
-        # scenario defers all of this to after the decision loop — the
-        # network model needs each event's actual payload bytes.
-        nxt = None
-        if not reactive:
-            for j in range(w):
-                sched.schedule(int(idx_np[j]), start=float(times[j]))
-            remaining = total_events - ev - w
-            nxt = sched.pop_window(min(W, remaining)) if remaining else None
-            if nxt is not None and len(nxt[1]) < N:
-                pre_d = ops.gather(data, jnp.asarray(nxt[1]))
-
-        V_w = (None if V_dev is None
-               else np.asarray(V_dev, np.float64)[row_of if full else
-                                                  slice(None)])
-        norms_w = (None if norms_dev is None
-                   else np.asarray(norms_dev, np.float64)[row_of if full else
-                                                          slice(None)])
-        # the policy's server-side threshold (EAFLM Eq. 3) is evaluated
-        # once per WINDOW, from the deltas as of window start — an
-        # intentional engine approximation: mid-window flushes (whenever
-        # buffer_size < window) advance the server deltas without
-        # re-thresholding.  The sequential engine recomputes per event;
-        # max_batch=1/buffer_size=1 is the bit-exact configuration.
-        thr = policy.window_threshold(
-            lambda: _tree_delta(prev_global, prev_prev_global))
-
-        dl_rel = np.empty(w, np.int64)      # per-event index into ver_trees
-        ver_trees: list = []                # distinct globals downloaded
-        ver_pos: dict = {}                  # server_version -> position
-        enc_downloads: list = []            # per-client lossy downlink trees
-        pending = None                      # final flush folded into commit
-        ev_up = np.zeros(w, np.int64)       # per-event on-the-wire bytes
-        ev_down = np.zeros(w, np.int64)
-        for j in range(w):
-            i = int(idx_np[j])
-            r = int(row_of[j])
-            t_j = float(times[j])
-            u0, d0 = comm.uplink_bytes, comm.downlink_bytes
-            if policy.reports:
-                comm.record_report(1)
-                if obs is not None:
-                    obs.report(i, t_j)
-            upload = policy.decide(
-                i, None if V_w is None else float(V_w[j]),
-                None if norms_w is None else float(norms_w[j]), thr)
-
-            if upload:
-                p0 = comm.upload_payload_bytes
-                if codec.is_identity:
-                    buffer.append((newp, r))
-                    comm.record_upload(1)
+            # ---- dispatch the window's device work --------------------
+            with _span(obs, "window.dispatch"):
+                rng, urng = jax.random.split(rng)
+                if full:
+                    # run in client order with keys permuted to arrival
+                    # positions: bit-exact with the gathered path, but the
+                    # three O(N*|params|) stack copies (gather, prev-grad
+                    # scatter, download scatter) vanish.  row(client i)
+                    # == i.
+                    inv = np.empty(N, np.int64)
+                    inv[idx_np] = np.arange(N)
+                    keys = jax.random.split(urng, N)[jnp.asarray(inv)]
+                    sub_base = client_params
+                    newp, eff, _ = keyed_update(client_params, data, keys)
+                    row_of = idx_np            # event j -> row in newp/eff
                 else:
-                    recon = _compressed_upload(
-                        codec, ef, comm, stacked_index(sub_base, r),
-                        stacked_index(newp, r), i,
-                        _enc_seed(run_cfg, ev + j, i, _UPLOAD), obs=obs,
-                        device=encode_on)
-                    buffer.append((jax.tree.map(lambda x: x[None], recon), 0))
-                staleness = server_version - model_version[i]
-                buf_stale.append(aggregator.stale_weight(staleness))
-                if obs is not None:
-                    obs.upload(i, t_j, staleness=int(staleness),
-                               nbytes=comm.upload_payload_bytes - p0,
-                               codec=codec.name)
-                if len(buffer) >= K:
-                    if (j == w - 1 and len(buffer) > 1 and foldable_flush
-                            and bcodec is None
-                            and all(ref is newp for ref, _ in buffer)):
-                        # window's final flush: fold into the commit call
-                        # (only this event can download the new version)
-                        rows = np.asarray([rr for _, rr in buffer], np.int32)
-                        coef, rho_sbar = buffered_coefs(
-                            buf_stale, aggregator.mix_rate)
-                        pending = (rows, coef, rho_sbar)
-                        if obs is not None:
-                            obs.flush(len(buffer), t_j, folded=True)
-                        server_version += 1
-                        buffer.clear()
-                        buf_stale.clear()
+                    idx = jnp.asarray(idx_np)
+                    sub_base = ops.gather(client_params, idx)
+                    d_w = pre_d if pre_d is not None else ops.gather(data,
+                                                                     idx)
+                    newp, eff, _ = local_update(sub_base, d_w, urng)
+                    row_of = np.arange(w)
+                pre_d = None
+
+                # the policy's declared stacked inputs: ONE vmapped
+                # dispatch per window each, with the device->host copy
+                # started immediately so the host can keep dispatching
+                # while it lands
+                V_dev = norms_dev = None
+                if policy.needs_values:
+                    if acc_cache is not None:
+                        # rows of newp map to clients: identity on the
+                        # fast path (client order), the window's arrival
+                        # ids otherwise
+                        accs = acc_cache.window_accs(
+                            newp, np.arange(N) if full else idx_np)
                     else:
-                        flush(t_j)
+                        accs = batch_eval(newp)
+                    pg_w = prev_grads if full else ops.gather(
+                        prev_grads, jnp.asarray(idx_np))
+                    V_dev = _host_async(values_fn(pg_w, eff, accs))
+                if policy.needs_norms:
+                    norms_dev = _host_async(norms_fn(eff))
 
-            if bcodec is None:
-                comm.record_broadcast(1)
-                if pending is not None and server_version not in ver_pos:
-                    dl_rel[j] = -1      # the in-commit flushed global
+            # ---- the one-window-deep pipeline --------------------------
+            # everything gating CANNOT change happens before we block on
+            # the gating inputs: restart each client from its own
+            # completion time (window execution must not barrier the
+            # simulated clock), pop the NEXT window, and pre-dispatch its
+            # data gather.  A reactive scenario defers all of this to
+            # after the decision loop — the network model needs each
+            # event's actual payload bytes.
+            with _span(obs, "window.pipeline"):
+                nxt = None
+                if not reactive:
+                    for j in range(w):
+                        sched.schedule(int(idx_np[j]), start=float(times[j]))
+                    remaining = total_events - ev - w
+                    nxt = (sched.pop_window(min(W, remaining)) if remaining
+                           else None)
+                    if nxt is not None and len(nxt[1]) < N:
+                        pre_d = ops.gather(data, jnp.asarray(nxt[1]))
+
+            # the host blocks here, on the gating inputs' device->host copy
+            with _span(obs, "window.wait"):
+                V_w = (None if V_dev is None
+                       else np.asarray(V_dev, np.float64)[
+                           row_of if full else slice(None)])
+                norms_w = (None if norms_dev is None
+                           else np.asarray(norms_dev, np.float64)[
+                               row_of if full else slice(None)])
+
+            with _span(obs, "window.decide"):
+                # the policy's server-side threshold (EAFLM Eq. 3) is
+                # evaluated once per WINDOW, from the deltas as of window
+                # start — an intentional engine approximation: mid-window
+                # flushes (whenever buffer_size < window) advance the
+                # server deltas without re-thresholding.  The sequential
+                # engine recomputes per event; max_batch=1/buffer_size=1
+                # is the bit-exact configuration.
+                thr = policy.window_threshold(
+                    lambda: _tree_delta(prev_global, prev_prev_global))
+
+                dl_rel = np.empty(w, np.int64)  # per-event ver_trees index
+                ver_trees: list = []            # distinct globals downloaded
+                ver_pos: dict = {}              # server_version -> position
+                enc_downloads: list = []        # per-client lossy downlinks
+                pending = None                  # final flush folded in commit
+                ev_up = np.zeros(w, np.int64)   # per-event on-the-wire bytes
+                ev_down = np.zeros(w, np.int64)
+                for j in range(w):
+                    i = int(idx_np[j])
+                    r = int(row_of[j])
+                    t_j = float(times[j])
+                    u0, d0 = comm.uplink_bytes, comm.downlink_bytes
+                    if policy.reports:
+                        comm.record_report(1)
+                        if obs is not None:
+                            obs.report(i, t_j)
+                    upload = policy.decide(
+                        i, None if V_w is None else float(V_w[j]),
+                        None if norms_w is None else float(norms_w[j]), thr)
+
+                    if upload:
+                        with _span(obs, "upload_path", client=i):
+                            p0 = comm.upload_payload_bytes
+                            if codec.is_identity:
+                                buffer.append((newp, r))
+                                comm.record_upload(1)
+                            else:
+                                recon = _compressed_upload(
+                                    codec, ef, comm,
+                                    stacked_index(sub_base, r),
+                                    stacked_index(newp, r), i,
+                                    _enc_seed(run_cfg, ev + j, i, _UPLOAD),
+                                    obs=obs, device=encode_on)
+                                buffer.append(
+                                    (jax.tree.map(lambda x: x[None], recon),
+                                     0))
+                            staleness = server_version - model_version[i]
+                            buf_stale.append(
+                                aggregator.stale_weight(staleness))
+                            if obs is not None:
+                                obs.upload(i, t_j, staleness=int(staleness),
+                                           nbytes=(comm.upload_payload_bytes
+                                                   - p0),
+                                           codec=codec.name)
+                            if len(buffer) >= K:
+                                if (j == w - 1 and len(buffer) > 1
+                                        and foldable_flush and bcodec is None
+                                        and all(ref is newp
+                                                for ref, _ in buffer)):
+                                    # window's final flush: fold into the
+                                    # commit call (only this event can
+                                    # download the new version)
+                                    rows = np.asarray(
+                                        [rr for _, rr in buffer], np.int32)
+                                    coef, rho_sbar = buffered_coefs(
+                                        buf_stale, aggregator.mix_rate)
+                                    pending = (rows, coef, rho_sbar)
+                                    if obs is not None:
+                                        obs.flush(len(buffer), t_j,
+                                                  folded=True)
+                                    server_version += 1
+                                    buffer.clear()
+                                    buf_stale.clear()
+                                else:
+                                    flush(t_j)
+
+                    if bcodec is None:
+                        comm.record_broadcast(1)
+                        if pending is not None and server_version not in \
+                                ver_pos:
+                            dl_rel[j] = -1  # the in-commit flushed global
+                        else:
+                            if server_version not in ver_pos:
+                                ver_pos[server_version] = len(ver_trees)
+                                ver_trees.append(global_params)
+                            dl_rel[j] = ver_pos[server_version]
+                    else:
+                        enc_downloads.append(_compressed_broadcast(
+                            bcodec, comm, global_params, 1,
+                            _enc_seed(run_cfg, ev + j, i, _BROADCAST),
+                            obs=obs))
+                    model_version[i] = server_version
+                    ev_up[j] = comm.uplink_bytes - u0
+                    ev_down[j] = comm.downlink_bytes - d0
+                    if obs is not None:
+                        obs.broadcast(i, t_j, nbytes=int(ev_down[j]),
+                                      codec=(None if bcodec is None
+                                             else bcodec.name))
+
+                if reactive:
+                    # byte-aware reschedule: each client restarts from its
+                    # own completion time plus the link delay its actual
+                    # payload cost
+                    for j in range(w):
+                        sched.schedule(int(idx_np[j]), start=float(times[j]),
+                                       upload_bytes=int(ev_up[j]),
+                                       download_bytes=int(ev_down[j]))
+                    remaining = total_events - ev - w
+                    nxt = (sched.pop_window(min(W, remaining)) if remaining
+                           else None)
+                    if nxt is not None and len(nxt[1]) < N:
+                        pre_d = ops.gather(data, jnp.asarray(nxt[1]))
                 else:
-                    if server_version not in ver_pos:
-                        ver_pos[server_version] = len(ver_trees)
-                        ver_trees.append(global_params)
-                    dl_rel[j] = ver_pos[server_version]
-            else:
-                enc_downloads.append(_compressed_broadcast(
-                    bcodec, comm, global_params, 1,
-                    _enc_seed(run_cfg, ev + j, i, _BROADCAST), obs=obs))
-            model_version[i] = server_version
-            ev_up[j] = comm.uplink_bytes - u0
-            ev_down[j] = comm.downlink_bytes - d0
+                    # already rescheduled (pipeline); ledger the bytes only
+                    for j in range(w):
+                        sched.account_bytes(int(idx_np[j]), int(ev_up[j]),
+                                            int(ev_down[j]))
+
+            # ---- commit: flush remainder + download write-back +
+            # prev-grad scatter, ONE donated jitted call -----------------
+            with _span(obs, "window.commit"):
+                if any(ref is newp for ref, _ in buffer):
+                    # detach leftover buffer entries from the window output
+                    # before it goes out of scope: under gating a
+                    # partially-full buffer would otherwise pin one full
+                    # (w, ...) stack per window until the flush — gather
+                    # just the buffered rows instead
+                    rows = np.asarray([r for ref, r in buffer
+                                       if ref is newp])
+                    sub = tree_gather(newp, rows)
+                    fresh = iter(range(len(rows)))
+                    buffer[:] = [(sub, next(fresh)) if ref is newp
+                                 else (ref, r) for ref, r in buffer]
+                sub_base = None  # release the window's download base
+
+                if pending is not None:
+                    prev_prev_global = prev_global
+                    prev_global = global_params
+                if bcodec is None:
+                    # the version count varies per window under gating, so
+                    # the stack is padded to the next power of two —
+                    # O(log W) compiled variants instead of one per
+                    # distinct count (padding rows are never indexed)
+                    if len(ver_trees) > 1:
+                        bucket = 1 << (len(ver_trees) - 1).bit_length()
+                        padded = ver_trees + [ver_trees[-1]] * (
+                            bucket - len(ver_trees))
+                    else:
+                        padded = ver_trees
+                    vstack = ops.stack(tuple(padded))
+                    # fast path: re-index the per-event versions by CLIENT
+                    # (row i of the new stack belongs to client i, whose
+                    # event was j = inv[i]); sub-full windows keep arrival
+                    # order
+                    rel_np = dl_rel[inv] if full else dl_rel
+                    rel = jnp.asarray(np.where(rel_np < 0, len(padded),
+                                               rel_np))
+                    if full:
+                        if pending is not None:
+                            global_params, client_params, prev_grads = \
+                                ops.commit_full_flush(global_params, vstack,
+                                                      rel, eff, newp,
+                                                      *pending)
+                        else:
+                            client_params, prev_grads = ops.commit_full(
+                                vstack, rel, eff)
+                    else:
+                        idx = jnp.asarray(idx_np)
+                        if pending is not None:
+                            global_params, client_params, prev_grads = \
+                                ops.commit_win_flush(
+                                    global_params, client_params,
+                                    prev_grads, idx, vstack, rel, eff, newp,
+                                    *pending)
+                        else:
+                            client_params, prev_grads = ops.commit_win(
+                                client_params, prev_grads, idx, vstack,
+                                rel, eff)
+                else:
+                    assert pending is None  # bcodec downloads never fold
+                    if full:
+                        # client order: client i received
+                        # enc_downloads[inv[i]]
+                        client_params = ops.place(ops.stack(
+                            tuple(enc_downloads[int(v)] for v in inv)))
+                        prev_grads = eff
+                    else:
+                        idx = jnp.asarray(idx_np)
+                        client_params = ops.scatter_donated(
+                            client_params, idx,
+                            ops.stack(tuple(enc_downloads)))
+                        prev_grads = ops.scatter_donated(prev_grads, idx,
+                                                         eff)
+
             if obs is not None:
-                obs.broadcast(i, t_j, nbytes=int(ev_down[j]),
-                              codec=None if bcodec is None else bcodec.name)
-
-        if reactive:
-            # byte-aware reschedule: each client restarts from its own
-            # completion time plus the link delay its actual payload cost
-            for j in range(w):
-                sched.schedule(int(idx_np[j]), start=float(times[j]),
-                               upload_bytes=int(ev_up[j]),
-                               download_bytes=int(ev_down[j]))
-            remaining = total_events - ev - w
-            nxt = sched.pop_window(min(W, remaining)) if remaining else None
-            if nxt is not None and len(nxt[1]) < N:
-                pre_d = ops.gather(data, jnp.asarray(nxt[1]))
-        else:
-            # already rescheduled (pipeline); ledger the bytes only
-            for j in range(w):
-                sched.account_bytes(int(idx_np[j]), int(ev_up[j]),
-                                    int(ev_down[j]))
-
-        if any(ref is newp for ref, _ in buffer):
-            # detach leftover buffer entries from the window output before
-            # it goes out of scope: under gating a partially-full buffer
-            # would otherwise pin one full (w, ...) stack per window until
-            # the flush — gather just the buffered rows instead
-            rows = np.asarray([r for ref, r in buffer if ref is newp])
-            sub = tree_gather(newp, rows)
-            fresh = iter(range(len(rows)))
-            buffer[:] = [(sub, next(fresh)) if ref is newp else (ref, r)
-                         for ref, r in buffer]
-        sub_base = None    # release the window's download-base reference
-
-        # ---- commit: flush remainder + download write-back + prev-grad
-        # scatter, ONE donated jitted call ------------------------------
-        if pending is not None:
-            prev_prev_global = prev_global
-            prev_global = global_params
-        if bcodec is None:
-            # the version count varies per window under gating, so the
-            # stack is padded to the next power of two — O(log W) compiled
-            # variants instead of one per distinct count (padding rows are
-            # never indexed)
-            if len(ver_trees) > 1:
-                bucket = 1 << (len(ver_trees) - 1).bit_length()
-                padded = ver_trees + [ver_trees[-1]] * (bucket
-                                                        - len(ver_trees))
-            else:
-                padded = ver_trees
-            vstack = ops.stack(tuple(padded))
-            # fast path: re-index the per-event versions by CLIENT (row i
-            # of the new stack belongs to client i, whose event was j =
-            # inv[i]); sub-full windows keep arrival order
-            rel_np = dl_rel[inv] if full else dl_rel
-            rel = jnp.asarray(np.where(rel_np < 0, len(padded), rel_np))
-            if full:
-                if pending is not None:
-                    global_params, client_params, prev_grads = \
-                        ops.commit_full_flush(global_params, vstack, rel,
-                                              eff, newp, *pending)
-                else:
-                    client_params, prev_grads = ops.commit_full(vstack, rel,
-                                                                eff)
-            else:
-                idx = jnp.asarray(idx_np)
-                if pending is not None:
-                    global_params, client_params, prev_grads = \
-                        ops.commit_win_flush(global_params, client_params,
-                                             prev_grads, idx, vstack, rel,
-                                             eff, newp, *pending)
-                else:
-                    client_params, prev_grads = ops.commit_win(
-                        client_params, prev_grads, idx, vstack, rel, eff)
-        else:
-            assert pending is None     # bcodec downloads are never folded
-            if full:
-                # client order: client i received enc_downloads[inv[i]]
-                client_params = ops.place(ops.stack(
-                    tuple(enc_downloads[int(v)] for v in inv)))
-                prev_grads = eff
-            else:
-                idx = jnp.asarray(idx_np)
-                client_params = ops.scatter_donated(
-                    client_params, idx, ops.stack(tuple(enc_downloads)))
-                prev_grads = ops.scatter_donated(prev_grads, idx, eff)
-
-        if obs is not None:
-            # one span per window: sim bounds = first/last completion,
-            # host duration = dispatch through commit (this point)
-            obs.window(w, float(times[0]), t_now, h0)
+                # one span per window: sim bounds = first/last completion,
+                # host duration = dispatch through commit (this point)
+                obs.window(w, float(times[0]), t_now, h0)
         prev_ev, ev = ev, ev + w
         epe = run_cfg.events_per_eval
         crossed = ev // epe - prev_ev // epe
@@ -579,39 +628,44 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
             # evaluation overlaps the next window's compute; a record whose
             # global model is bit-identical to the previous one (no flush
             # since) reuses its scalar outright
-            h0e = obs.host_now() if obs is not None else 0.0
-            reused = last_eval[0] == server_version
-            if reused:
-                acc = last_eval[1]     # bit-identical model: reuse (exact)
-            else:
-                acc = _host_async(evaluate_fn(global_params))
-                last_eval = (server_version, acc)
-            if obs is not None:
-                # the acc scalar stays deferred — the hook never reads it
-                obs.eval_event(ev, t_now, h0e, boundaries=crossed,
-                               reused=reused)
-            records.append(RoundRecord(round=ev, time=t_now, global_acc=acc,
-                                       uploads_so_far=comm.model_uploads,
-                                       boundaries_crossed=crossed))
+            with _annotate(obs, "eval"):
+                h0e = obs.host_now() if obs is not None else 0.0
+                reused = last_eval[0] == server_version
+                if reused:
+                    acc = last_eval[1]  # bit-identical model: reuse (exact)
+                else:
+                    acc = _host_async(evaluate_fn(global_params))
+                    last_eval = (server_version, acc)
+                if obs is not None:
+                    # the acc scalar stays deferred — the hook never reads
+                    obs.eval_event(ev, t_now, h0e, boundaries=crossed,
+                                   reused=reused)
+                records.append(RoundRecord(
+                    round=ev, time=t_now, global_acc=acc,
+                    uploads_so_far=comm.model_uploads,
+                    boundaries_crossed=crossed))
             if verbose:
                 progress(f"[{run_cfg.algorithm}/batched] ev {ev:5d} "
                          f"t={t_now:8.1f} acc={float(acc):.4f} "
                          f"uploads={comm.model_uploads}")
         if ckpt_every and ev // ckpt_every > prev_ev // ckpt_every:
-            _save_ckpt()
+            with _annotate(obs, "checkpoint"):
+                _save_ckpt()
 
         if nxt is None:
             break
         times, idx_np = nxt
 
     if obs is not None:
-        obs.profile_stop()
         obs.sampler_stop()
-    if buffer:  # partial buffer at run end — flush so no update is lost
-        flush(float(sched.now))
-
-    for r in records:                  # resolve the deferred eval scalars
-        r.global_acc = float(r.global_acc)
-    res = RunResult(run_cfg.algorithm, records, comm,
-                    run_cfg.target_acc).finalize_target()
-    return _finish_obs(_attach_sim_result(res, sched), obs)
+    with _span(obs, "run.finish"):
+        if buffer:  # partial buffer at run end — flush so no update is lost
+            flush(float(sched.now))
+        for r in records:              # resolve the deferred eval scalars
+            r.global_acc = float(r.global_acc)
+        res = _attach_sim_result(RunResult(
+            run_cfg.algorithm, records, comm,
+            run_cfg.target_acc).finalize_target(), sched)
+    if obs is not None:
+        obs.profile_stop()
+    return _finish_obs(res, obs)

@@ -68,6 +68,17 @@ def _finish_obs(res, obs):
     return res
 
 
+def _span(obs, name, **kw):
+    """``obs.span(name, ...)``, or a do-nothing context when obs is off."""
+    return nullcontext() if obs is None else obs.span(name, **kw)
+
+
+def _annotate(obs, name, **tags):
+    """``obs.annotate(name, ...)`` (the profiler half of a span, for a
+    block whose record a hook writes), or nothing when obs is off."""
+    return nullcontext() if obs is None else obs.annotate(name, **tags)
+
+
 # ------------------------------------------------- scenario plumbing ---
 
 def _scenario_models(run_cfg, num_clients):
@@ -290,13 +301,20 @@ def _engine_jits(sharding):
     def cons(tree):
         return jax.tree.map(_cons, tree)
 
-    gather = jax.jit(lambda s, i: cons(tree_gather(s, i)))
+    # named functions, so that each program has its own name in a trace
+    @jax.jit
+    def gather_rows(s, i):
+        return cons(tree_gather(s, i))
+
     # NOT constrained: stack() builds the download-version stack, whose
     # leading dim is versions, not clients — constraining it whenever the
     # version count happened to divide the device count would spread the
     # versions across devices and turn every commit's v[rel] gather into
     # an all-gather.  Client-axis stacks go through place() explicitly.
-    stack = jax.jit(lambda trees: tree_stack(list(trees)))
+    @jax.jit
+    def stack_trees(trees):
+        return tree_stack(list(trees))
+
     place = jax.jit(cons)
 
     @partial(jax.jit, donate_argnums=(0, 1))
@@ -343,7 +361,8 @@ def _engine_jits(sharding):
         return cons(tree_scatter(s, idx, rows))
 
     return SimpleNamespace(
-        gather=gather, stack=stack, place=place, commit_win=commit_win,
+        gather=gather_rows, stack=stack_trees, place=place,
+        commit_win=commit_win,
         commit_win_flush=commit_win_flush, commit_full=commit_full,
         commit_full_flush=commit_full_flush, scatter_donated=scatter_donated)
 
@@ -400,10 +419,13 @@ def _round_helpers(run_cfg, client_eval_fn):
     # caching would pin the eval fn's device arrays past the run
     # flcheck: ignore[jit-in-hot-path]
     batch_eval = jax.jit(_client_eval_vmap(client_eval_fn))
+
+    def communication_values(gp, gc, accs):
+        return value_lib.communication_values_stacked(gp, gc, accs, N,
+                                                      sq_diff_fn=sq_diff)
+
     # flcheck: ignore[jit-in-hot-path]
-    values_fn = jax.jit(
-        lambda gp, gc, accs: value_lib.communication_values_stacked(
-            gp, gc, accs, N, sq_diff_fn=sq_diff))
+    values_fn = jax.jit(communication_values)
     # flcheck: ignore[jit-in-hot-path]
     grad_norms_fn = jax.jit(jax.vmap(tree_sq_norm))
     return batch_eval, values_fn, grad_norms_fn
@@ -436,10 +458,13 @@ def _build_event_helpers(num_clients, client_eval_fn, sq_diff):
     # fallback), so the zero-recompile-rerun contract holds
     # flcheck: ignore[jit-in-hot-path]
     batch_eval = jax.jit(_client_eval_vmap(client_eval_fn))
+
+    def communication_value(pg, gc, a):
+        return value_lib.communication_value(pg, gc, a, num_clients,
+                                             sq_diff_fn=sq_diff)
+
     # flcheck: ignore[jit-in-hot-path]
-    values_fn = jax.jit(jax.vmap(
-        lambda pg, gc, a: value_lib.communication_value(
-            pg, gc, a, num_clients, sq_diff_fn=sq_diff)))
+    values_fn = jax.jit(jax.vmap(communication_value))
     # flcheck: ignore[jit-in-hot-path]
     norms_fn = jax.jit(jax.vmap(tree_sq_norm))
     return batch_eval, values_fn, norms_fn

@@ -162,13 +162,9 @@ def run_event_driven(run_cfg, *, init_params_fn, loss_fn, fed_data,
         rng, urng = jax.random.split(rng)
         one = jax.tree.map(lambda x: x[None], client_params[i])
         d_i = {k: v[i:i + 1] for k, v in data.items()}
-        h0 = obs.host_now() if obs is not None else 0.0
         newp_s, eff_s, _ = local_update(one, d_i, urng)
         newp = jax.tree.map(lambda x: x[0], newp_s)
         eff_grad = jax.tree.map(lambda x: x[0], eff_s)
-        if obs is not None:
-            # sim span: the client's whole local round ended at t_now
-            obs.local_update(t_now, t_now, h0, client=i)
 
         # the policy's declared inputs, computed as size-1 stacked calls
         # through the same jitted helpers the batched engine uses

@@ -146,10 +146,7 @@ def run_round_based(run_cfg, *, init_params_fn, loss_fn, fed_data,
         # simulated timeline is the round index (matching record.time)
         sim = now if compute is not None else float(t)
         rng, urng = jax.random.split(rng)
-        h0 = obs.host_now() if obs is not None else 0.0
         stacked, eff_grads, losses = local_update(stacked, data, urng)
-        if obs is not None:
-            obs.local_update(sim, sim, h0, clients=N)
         # per-client eval: needed by Eq.1 values and/or the round record
         client_accs = (batch_eval(stacked)
                        if policy.needs_values or run_cfg.record_client_accs

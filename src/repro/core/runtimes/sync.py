@@ -134,10 +134,7 @@ def _run_sync_barrier(run_cfg, policy, aggregator, init_params_fn, loss_fn,
         part = _participation_mask(part_rng, run_cfg.participation, N)
         stacked = jax.tree.map(lambda x: jnp.broadcast_to(x, (N,) + x.shape),
                                client_base)
-        h0 = obs.host_now() if obs is not None else 0.0
         stacked, eff_grads, _ = local_update(stacked, data, urng)
-        if obs is not None:
-            obs.local_update(now, now, h0, clients=N)
         round_times = np.array([speed.sample(c, now) for c in range(N)])
         busy[part] += round_times[part]   # non-participants idle all round
         u0, d0 = up_bytes.copy(), down_bytes.copy()

@@ -3,7 +3,7 @@
 ``ObsConfig`` is the one knob surface: what to collect (trace, metrics),
 where to export it (JSONL, Chrome ``trace_event`` JSON, console
 summary), and the opt-in ``jax.profiler`` hook around the batched
-engine's hot loop.  ``FLRunConfig.obs`` / ``Federation(obs=...)``
+engine's run.  ``FLRunConfig.obs`` / ``Federation(obs=...)``
 accept ``None`` (off — the default, zero overhead), ``True`` (in-memory
 collection with defaults), an ``ObsConfig``, or a plain dict of
 ``ObsConfig`` fields.
@@ -33,9 +33,10 @@ class ObsConfig:
     # hard cap on in-memory trace events; beyond it events are dropped
     # and counted (never silently — the summary and snapshot report it)
     max_events: int = 1_000_000
-    # opt-in: wrap the batched engine's hot loop in
+    # opt-in: wrap the batched engine's run in
     # jax.profiler.start_trace(jax_profile) / stop_trace — a TensorBoard-
-    # loadable device profile of the window pipeline
+    # loadable device profile of the window pipeline, carrying the run's
+    # repro.* host spans on the device's clock
     jax_profile: Optional[str] = None
     # opt-in live telemetry (repro.obs.live): seconds between background
     # MetricsSampler snapshots of the registry (None = no sampler thread,

@@ -1,11 +1,17 @@
 """The ``Observer`` — the one object the runtimes talk to.
 
 Semantic hooks (``upload`` / ``broadcast`` / ``report`` / ``window`` /
-``local_update`` / ``flush`` / ``eval_event`` / ``failure``) each feed
-both the dual-timeline tracer and the metrics registry in one call, so
-the runtimes stay one-line-per-site and the counters are guaranteed to
+``flush`` / ``eval_event`` / ``failure``) each feed both the
+dual-timeline tracer and the metrics registry in one call, so the
+runtimes stay one-line-per-site and the counters are guaranteed to
 agree with the trace (tests/test_obs.py asserts both against
 ``CommStats``).
+
+Host spans (``span`` / ``timed`` / ``annotate``) also open a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>``: in any
+``jax.profiler`` trace they lie on the profiler's host plane, on the
+device trace's clock, so the device's idle time can be put down to what
+the host was doing.
 
 Off is *off*: ``FLRunConfig.obs=None`` means the runtimes carry a
 ``None`` and every hook site is behind an ``if obs is not None`` — the
@@ -33,8 +39,17 @@ class Observer:
         self.meta.update(cfg.metadata)
         self.metrics = MetricsRegistry()
         self.tracer = Tracer(cfg.max_events) if cfg.trace else None
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         compile_tracking.install()
         self._compiles0 = compile_tracking.compile_count()
+        if self.tracer is not None:
+            # what the process spent on compiles and cache loads before
+            # this run (totals since install()): a driver that installs at
+            # its entry reads its set-up's here
+            self.tracer.event("compile_totals",
+                              **compile_tracking.compile_stats())
+            compile_tracking.subscribe(self._compiled)
         # pre-bound metric objects for the per-event hooks: the hooks run
         # inside the engines' decision loops, so they skip the registry
         # name lookup (get-or-create) on every call
@@ -48,7 +63,6 @@ class Observer:
         self._m_bcast_bytes = m.counter("broadcast_bytes")
         self._m_windows = m.counter("windows")
         self._m_window_size = m.hist("window_size")
-        self._m_local_updates = m.counter("local_updates")
         self._m_flushes = m.counter("flushes")
         self._m_flush_k = m.hist("flush_k")
         # serve-loop hooks (repro.serve, docs/SERVING.md): depth of the
@@ -107,16 +121,6 @@ class Observer:
         self._m_window_size.observe(size)
         if self.tracer:
             self.tracer.span("window", sim0, sim1, host_start, size=size)
-
-    def local_update(self, sim0, sim1, host_start, *, client=None,
-                     clients=None):
-        """A local-update dispatch: per event (sequential loop, client=)
-        or per window/round (batched & round runtimes, clients=count)."""
-        self._m_local_updates.inc()
-        if self.tracer:
-            tags = {} if clients is None else {"clients": clients}
-            self.tracer.span("local_update", sim0, sim1, host_start,
-                             client=client, **tags)
 
     def flush(self, k, sim, *, folded=False):
         """A buffered-aggregation flush of k reconstructions (the batched
@@ -242,22 +246,57 @@ class Observer:
             self.tracer.span("resume" if restored else "checkpoint",
                              None, None, host_start, step=step)
 
+    def annotate(self, name, *, client=None, **tags):
+        """The profiler half of a span: a ``TraceAnnotation``
+        ``repro.<name>`` tagged with ``client`` and ``tags``, for a block
+        whose trace record a hook writes (``window``, ``eval_event``,
+        ``checkpoint``).  Free of any wait on the device."""
+        if client is not None:
+            tags["client"] = client
+        return self._annotation(f"repro.{name}", **tags)
+
+    @contextmanager
+    def span(self, name, *, sim=None, sim_end=None, client=None, **tags):
+        """Host span around a code block, on both clocks: the profiler's
+        ``repro.<name>`` annotation while it runs, then the ``name`` span
+        record in the tracer (simulated bounds ``sim``..``sim_end``).
+        The span only reads the host clock: it never waits on the device
+        nor reads a device value."""
+        h0 = self.host_now()
+        with self.annotate(name, client=client, **tags):
+            try:
+                yield
+            finally:
+                if self.tracer:
+                    self.tracer.span(name, sim, sim if sim_end is None
+                                     else sim_end, h0, client=client,
+                                     **tags)
+
     @contextmanager
     def timed(self, name, *, sim=None, client=None, **tags):
-        """Host-timed span around a code block (codec encodes etc.)."""
-        h0 = self.host_now()
+        """A ``span`` that also counts its calls (``<name>_calls``):
+        codec encodes and decodes."""
         try:
-            yield
+            with self.span(name, sim=sim, client=client, **tags):
+                yield
         finally:
             self.metrics.counter(f"{name}_calls").inc()
-            if self.tracer:
-                self.tracer.span(name, sim, sim, h0, client=client, **tags)
+
+    def _compiled(self, fun_name, secs, cache_hit):
+        """A backend step (compile or persistent-cache load) during the
+        run: a ``compile`` instant and a ``repro.compile`` marker at its
+        end in the profiler trace, so a trace shows what (re)compiled."""
+        self.tracer.event("compile", fun_name=fun_name, secs=secs,
+                          cache_hit=cache_hit)
+        with self.annotate("compile", fun_name=fun_name, secs=secs,
+                           cache_hit=int(cache_hit)):
+            pass
 
     def profile_start(self):
         """Start the opt-in device profiler (``cfg.jax_profile`` = a
         trace directory, TensorBoard-loadable); no-op otherwise.  The
-        batched engine brackets its hot loop with start/stop directly so
-        the loop body needs no extra indentation level."""
+        batched engine brackets its whole run with start/stop, so the
+        profile carries its ``repro.*`` spans."""
         if self.cfg.jax_profile:
             import jax
             jax.profiler.start_trace(self.cfg.jax_profile)
@@ -266,15 +305,6 @@ class Observer:
         if self.cfg.jax_profile:
             import jax
             jax.profiler.stop_trace()
-
-    @contextmanager
-    def jax_profile(self):
-        """``profile_start``/``profile_stop`` as a context manager."""
-        self.profile_start()
-        try:
-            yield
-        finally:
-            self.profile_stop()
 
     def sampler_start(self):
         """Start the opt-in background MetricsSampler
@@ -300,6 +330,8 @@ class Observer:
         files, attach ``metrics``/``trace_path`` to the ``RunResult``,
         and print the summary if asked.  Returns the metrics snapshot."""
         self.sampler_stop()
+        if self.tracer is not None:
+            compile_tracking.unsubscribe(self._compiled)
         if self.sampler is not None:
             self.metrics.gauge("metric_samples").set(len(self.sampler))
         self.metrics.gauge("jit_compiles").set(

@@ -204,6 +204,27 @@ class TestBitExact:
         on = _run(setup, alg, mode, obs=True, **kw)
         assert _numeric(off) == _numeric(on)
 
+    def test_obs_off_opens_no_annotation(self, setup, monkeypatch):
+        """Off is off for the profiler too: a run with obs=None opens no
+        ``repro.*`` TraceAnnotation, one with obs on opens its spans."""
+        import jax.profiler
+
+        opened = []
+        real = jax.profiler.TraceAnnotation
+
+        class Counting(real):
+            def __init__(self, name, **tags):
+                opened.append(name)
+                super().__init__(name, **tags)
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+        kw = dict(engine="batched", max_batch=3, buffer_size=2)
+        _run(setup, "vafl", "event", **kw)
+        assert not [n for n in opened if n.startswith("repro.")]
+        _run(setup, "vafl", "event", obs=True, **kw)
+        assert {"repro.run.start", "repro.window", "repro.window.decide",
+                "repro.run.finish"} <= set(opened)
+
     def test_deterministic_trace(self, setup, tmp_path):
         kw = dict(mode="event", engine="batched", max_batch=3,
                   buffer_size=2)
@@ -211,8 +232,12 @@ class TestBitExact:
         _, _, ev2 = _traced(setup, "vafl", kw, tmp_path, "det2")
 
         def strip_host(events):
+            # compile records say what this process had compiled so far
+            # or had yet to compile, which differs between two identical
+            # runs in one process
             return [{k: v for k, v in e.items()
-                     if k not in ("host", "host_dur")} for e in events]
+                     if k not in ("host", "host_dur")} for e in events
+                    if e["name"] not in ("compile", "compile_totals")]
         assert strip_host(ev1) == strip_host(ev2)
 
 
@@ -362,3 +387,82 @@ class TestConfig:
         install()
         install()  # idempotent
         assert compile_count() >= 0
+
+
+class TestCompileStats:
+    def test_a_miss_then_a_hit(self, tmp_path):
+        """The backend step of one new jit is a persistent-cache miss
+        (compiled and written) in a fresh cache, and a hit (loaded) once
+        the in-memory caches are cleared; both count as backend steps."""
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental.compilation_cache import compilation_cache
+        from repro.obs import compile_stats, install
+
+        install()
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs",
+                "jax_persistent_cache_min_entry_size_bytes")
+        old = {k: getattr(jax.config, k) for k in keys}
+        x = jnp.arange(5.0)
+        f = jax.jit(lambda v: v * 3.0 + 1.0)
+        try:
+            jax.config.update(keys[0], str(tmp_path))
+            jax.config.update(keys[1], 0.0)
+            jax.config.update(keys[2], 0)
+            compilation_cache.reset_cache()
+            s0 = compile_stats()
+            f(x).block_until_ready()
+            s1 = compile_stats()
+            jax.clear_caches()
+            f(x).block_until_ready()
+            s2 = compile_stats()
+        finally:
+            for k, v in old.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
+
+        def step(a, b):
+            return {k: b[k] - a[k] for k in ("backend_count", "cache_hits",
+                                             "cache_misses")}
+        assert step(s0, s1) == {"backend_count": 1, "cache_hits": 0,
+                                "cache_misses": 1}
+        assert step(s1, s2) == {"backend_count": 1, "cache_hits": 1,
+                                "cache_misses": 0}
+        assert s2["cache_retrieval_s"] > s1["cache_retrieval_s"]
+        for k in ("trace", "lower", "backend"):
+            assert s2[f"{k}_count"] >= s0[f"{k}_count"] + 1
+            assert s2[f"{k}_s"] > s0[f"{k}_s"]
+
+    def test_traced_run_records_its_compiles(self, setup, tmp_path):
+        """A traced run's backend steps become ``compile`` records naming
+        the program, as many as the ``jit_compiles`` gauge counts."""
+        import jax
+
+        jax.clear_caches()
+        res, _, events = _traced(
+            setup, "vafl", dict(mode="event", engine="batched",
+                                max_batch=3), tmp_path, "compiles", rounds=1)
+        compiles = [e for e in events if e["name"] == "compile"]
+        assert len(compiles) == res.metrics["gauges"]["jit_compiles"] > 0
+        assert "jit(update)" in {e["fun_name"] for e in compiles}
+        assert all(isinstance(e["cache_hit"], bool) and e["secs"] >= 0
+                   for e in compiles)
+
+    def test_traced_run_opens_with_the_compile_totals(self, setup, tmp_path):
+        """A traced run's first record holds the process's compile and
+        cache totals as the run started: what came before it."""
+        from repro.obs import compile_stats, install
+
+        install()
+        before = compile_stats()
+        _, _, events = _traced(
+            setup, "vafl", dict(mode="event", engine="batched",
+                                max_batch=3), tmp_path, "totals", rounds=1)
+        after = compile_stats()
+        totals = [e for e in events if e["name"] == "compile_totals"]
+        assert len(totals) == 1 and events[0] is totals[0]
+        rec = totals[0]
+        assert set(before) <= set(rec)
+        for k in before:
+            assert before[k] <= rec[k] <= after[k], k

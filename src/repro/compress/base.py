@@ -27,9 +27,10 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -53,15 +54,82 @@ class Payload:
                    + self.wire_overhead)
 
 
+@dataclass
+class DeviceWire:
+    """The wire cost of one transfer that a device round trip made
+    (``Codec.device_roundtrip``): the planes are never built, only the
+    count of kept entries, which stays on the device.  ``nbytes`` (what
+    the matching :class:`Payload` would count) reads it back; to read
+    many at once, use :func:`wire_nbytes`."""
+    kept: jax.Array               # int32 device scalar
+    bytes_per_kept: int
+    wire_overhead: int = 0
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.kept) * self.bytes_per_kept + self.wire_overhead
+
+
+@jax.jit
+def _stack_counts(counts):
+    return jnp.stack(counts)
+
+
+def wire_nbytes(wires) -> list:
+    """The ``nbytes`` of each of ``wires`` (:class:`Payload` or
+    :class:`DeviceWire`), the device wires' counts read back in ONE
+    device-to-host copy.  The stack is padded to the next power of two,
+    so that the count of wires compiles O(log n) variants."""
+    dev = [w.kept for w in wires if isinstance(w, DeviceWire)]
+    kept = iter(())
+    if dev:
+        bucket = 1 << (len(dev) - 1).bit_length()
+        kept = iter(np.asarray(_stack_counts(
+            tuple(dev + dev[-1:] * (bucket - len(dev))))).tolist())
+    return [next(kept) * w.bytes_per_kept + w.wire_overhead
+            if isinstance(w, DeviceWire) else w.nbytes for w in wires]
+
+
+class RowDelta(NamedTuple):
+    """An update named by where it lives: row ``crow`` of the stacked
+    ``client`` trees minus row ``brow`` of the stacked ``base`` trees (the
+    model the client downloaded), in fp32.  A device round trip forms it
+    inside its own program; ``tree()`` forms it as a program of its own."""
+    base: Any
+    brow: Any
+    client: Any
+    crow: Any
+
+    def tree(self):
+        return _row_delta(self)
+
+
+@jax.jit
+def _row_delta(rows: RowDelta):
+    return jax.tree.map(
+        lambda c, b: (c[rows.crow].astype(jnp.float32)
+                      - b[rows.brow].astype(jnp.float32)),
+        rows.client, rows.base)
+
+
 class Codec:
     """encode(tree, seed) -> Payload; decode(Payload) -> tree.
 
     decode(encode(t)) has the same structure/shapes/dtypes as t; equality
     only holds for identity.  ``seed`` must vary per transfer (the server
     derives it from round/client) so stochastic rounding stays unbiased
-    across rounds while each payload remains reproducible."""
+    across rounds while each payload remains reproducible.
+
+    A codec may also offer ``device_roundtrip(tree, residual, *, seed)
+    -> (decoded, new_residual, DeviceWire)``: one compiled program that
+    forms the update (``tree`` may be a :class:`RowDelta`), folds in the
+    error-feedback residual (or None), encodes, decodes and forms the new
+    residual, reads nothing back to the host, and gives the same bits as
+    the ``Payload`` path (``repro.compress.error_feedback
+    .compress_update``).  ``None`` where it has none."""
     name: str = "codec"
     is_identity: bool = False
+    device_roundtrip = None
 
     def encode(self, tree, *, seed: int = 0) -> Payload:
         raise NotImplementedError

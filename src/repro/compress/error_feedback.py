@@ -16,7 +16,7 @@ from typing import Dict
 
 from repro.common.pytree import tree_add, tree_sub
 
-from repro.compress.base import Codec, Payload
+from repro.compress.base import Codec, RowDelta
 
 
 class ErrorFeedback:
@@ -37,13 +37,41 @@ class ErrorFeedback:
         if self.enabled:
             self.residuals[cid] = tree_sub(target, decoded)
 
+    def residual(self, cid: int):
+        """e_i as ``apply`` would fold it in, or None."""
+        return self.residuals.get(cid) if self.enabled else None
 
-def compress_update(codec: Codec, ef: ErrorFeedback, cid: int, tree, *,
-                    seed: int = 0):
-    """One client->server transfer: EF-corrected encode + server decode.
-    Returns (payload, decoded) with ef already advanced."""
+    def store(self, cid: int, residual):
+        """Keep e_i that a device round trip formed."""
+        if self.enabled:
+            self.residuals[cid] = residual
+
+
+def compress_payload(codec: Codec, ef: ErrorFeedback, cid: int, tree, *,
+                     seed: int = 0):
+    """One client->server transfer through the wire planes: EF-corrected
+    encode + server decode.  Returns (payload, decoded) with ef already
+    advanced.  The serve loop's path: a transport ships the planes."""
     target = ef.apply(cid, tree)
     payload = codec.encode(target, seed=seed)
     decoded = codec.decode(payload)
     ef.update(cid, target, decoded)
     return payload, decoded
+
+
+def compress_update(codec: Codec, ef: ErrorFeedback, cid: int, tree, *,
+                    seed: int = 0):
+    """One client->server transfer inside one process: the codec's device
+    round trip where it has one (one compiled program, the wire cost a
+    ``DeviceWire`` whose count stays on the device), else
+    ``compress_payload``.  ``tree`` is the update or a ``RowDelta``.
+    Returns (wire, decoded) with ef already advanced; ``wire.nbytes`` is
+    the same either way."""
+    if codec.device_roundtrip is None:
+        if isinstance(tree, RowDelta):
+            tree = tree.tree()
+        return compress_payload(codec, ef, cid, tree, seed=seed)
+    decoded, residual, wire = codec.device_roundtrip(
+        tree, ef.residual(cid), seed=seed)
+    ef.store(cid, residual)
+    return wire, decoded

@@ -57,6 +57,7 @@ import repro.checkpoint.store as ck
 from repro.algorithms.base import Aggregator
 from repro.common.pytree import (stacked_index, tree_bytes, tree_gather,
                                  tree_shard)
+from repro.compress import DeviceWire, wire_nbytes
 from repro.core.aggregation import buffered_coefs, buffered_mix
 from repro.core.client import make_local_update, make_local_update_keyed
 from repro.core.metrics import CommStats, RoundRecord, RunResult
@@ -118,6 +119,12 @@ class _AccCache:
         return jnp.asarray(self.acc[clients])
 
 
+def _entry(ref, row):
+    """The tree of one buffer entry: row ``row`` of the stacked ``ref``,
+    or ``ref`` itself where ``row`` is None (a codec reconstruction)."""
+    return ref if row is None else stacked_index(ref, row)
+
+
 def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
                        fed_data, evaluate_fn, client_eval_fn, speed,
                        net=None, avail=None, verbose=False) -> RunResult:
@@ -134,7 +141,7 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
     total_events = run_cfg.rounds * N
     # the FedBuff buffer: (stacked_tree, row) references — rows of the
     # window's vmapped output for identity uploads (client ids on the
-    # fast path, window positions otherwise), size-1 stacks for codec
+    # fast path, window positions otherwise), (tree, None) for codec
     # reconstructions; gathered/stacked only at flush time
     buffer: list = []
     buf_stale: list = []              # their staleness weights s(tau)
@@ -207,7 +214,7 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
         # body must bundle the already-popped NEXT window alongside the
         # scheduler snapshot; buffered updates are materialized to host
         # trees (their stacked-window sources don't outlive the iteration)
-        # and restored as size-1 stacks — exactly how codec
+        # and restored as unstacked trees — exactly how codec
         # reconstructions enter the buffer, so the flush math is
         # unchanged.
         ckpt_path, ckpt_every = (run_cfg.checkpoint_path,
@@ -233,9 +240,7 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
             records = list(st["records"])
             if st["last_eval"] is not None:
                 last_eval = (int(st["last_eval"][0]), st["last_eval"][1])
-            buffer = [(jax.tree.map(lambda x: x[None],
-                                    ck.tree_to_device(t)), 0)
-                      for t in st["buffer"]]
+            buffer = [(ck.tree_to_device(t), None) for t in st["buffer"]]
             buf_stale = list(st["buf_stale"])
             if st["policy"] is not None:
                 policy.set_state(st["policy"])
@@ -275,14 +280,13 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
         prev_prev_global = prev_global
         prev_global = global_params
         if len(buffer) == 1:          # bit-exact sequential mix (K=1 path)
-            ref, row = buffer[0]
             global_params = buffered_mix(
-                global_params, [stacked_index(ref, row)], buf_stale,
+                global_params, [_entry(*buffer[0])], buf_stale,
                 aggregator.mix_rate, mix=aggregator.mix)
         else:
             groups: list = []         # consecutive same-source rows
             for ref, row in buffer:
-                if groups and groups[-1][0] is ref:
+                if row is not None and groups and groups[-1][0] is ref:
                     groups[-1][1].append(row)
                 else:
                     groups.append((ref, [row]))
@@ -291,7 +295,8 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
             else:                     # buffer spans windows/codec payloads
                 src = jax.tree.map(
                     lambda *xs: jnp.concatenate(xs, 0),
-                    *[tree_gather(ref, np.asarray(rows))
+                    *[jax.tree.map(lambda x: x[None], ref) if rows == [None]
+                      else tree_gather(ref, np.asarray(rows))
                       for ref, rows in groups])
                 rows = range(len(buffer))
             coef, rho_sbar = buffered_coefs(buf_stale, aggregator.mix_rate)
@@ -321,7 +326,7 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
                         for r in records],
             "last_eval": (None if last_eval[0] is None
                           else (int(last_eval[0]), float(last_eval[1]))),
-            "buffer": [ck.tree_to_host(stacked_index(ref, row))
+            "buffer": [ck.tree_to_host(_entry(ref, row))
                        for ref, row in buffer],
             "buf_stale": list(buf_stale),
             "policy": policy.state(),
@@ -436,6 +441,7 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
                 ver_trees: list = []            # distinct globals downloaded
                 ver_pos: dict = {}              # server_version -> position
                 enc_downloads: list = []        # per-client lossy downlinks
+                sent: list = []                 # codec uploads, by event
                 pending = None                  # final flush folded in commit
                 ev_up = np.zeros(w, np.int64)   # per-event on-the-wire bytes
                 ev_down = np.zeros(w, np.int64)
@@ -454,28 +460,26 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
 
                     if upload:
                         with _span(obs, "upload_path", client=i):
-                            p0 = comm.upload_payload_bytes
+                            staleness = server_version - model_version[i]
                             if codec.is_identity:
                                 buffer.append((newp, r))
                                 comm.record_upload(1)
+                                if obs is not None:
+                                    obs.upload(i, t_j,
+                                               staleness=int(staleness),
+                                               nbytes=comm.model_bytes,
+                                               codec=codec.name)
                             else:
-                                recon = _compressed_upload(
-                                    codec, ef, comm,
-                                    stacked_index(sub_base, r),
-                                    stacked_index(newp, r), i,
+                                # the reconstruction, an unstacked tree;
+                                # its bytes are counted after the loop
+                                recon, wire = _compressed_upload(
+                                    codec, ef, sub_base, r, newp, r, i,
                                     _enc_seed(run_cfg, ev + j, i, _UPLOAD),
                                     obs=obs, device=encode_on)
-                                buffer.append(
-                                    (jax.tree.map(lambda x: x[None], recon),
-                                     0))
-                            staleness = server_version - model_version[i]
+                                buffer.append((recon, None))
+                                sent.append((j, i, t_j, staleness, wire))
                             buf_stale.append(
                                 aggregator.stale_weight(staleness))
-                            if obs is not None:
-                                obs.upload(i, t_j, staleness=int(staleness),
-                                           nbytes=(comm.upload_payload_bytes
-                                                   - p0),
-                                           codec=codec.name)
                             if len(buffer) >= K:
                                 if (j == w - 1 and len(buffer) > 1
                                         and foldable_flush and bcodec is None
@@ -520,6 +524,18 @@ def _run_event_batched(run_cfg, policy, aggregator, init_params_fn, loss_fn,
                         obs.broadcast(i, t_j, nbytes=int(ev_down[j]),
                                       codec=(None if bcodec is None
                                              else bcodec.name))
+
+                # the codec uploads' wire bytes: the device round trips'
+                # counts read back in one copy, now that the decisions are
+                # made
+                for (j, i, t_j, staleness, wire), nbytes in zip(
+                        sent, wire_nbytes([u[-1] for u in sent])):
+                    comm.record_upload(1, nbytes=nbytes)
+                    ev_up[j] += nbytes
+                    if obs is not None:
+                        obs.upload(i, t_j, staleness=int(staleness),
+                                   nbytes=nbytes, codec=codec.name,
+                                   device_codec=isinstance(wire, DeviceWire))
 
                 if reactive:
                     # byte-aware reschedule: each client restarts from its

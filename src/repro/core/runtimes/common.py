@@ -16,10 +16,12 @@ from types import SimpleNamespace
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.common.pytree import (stacked_index, tree_gather, tree_scatter,
                                  tree_stack, tree_sq_norm)
-from repro.compress import ErrorFeedback, compress_update, get_codec
+from repro.compress import (DeviceWire, ErrorFeedback, RowDelta,
+                            compress_update, get_codec, wire_nbytes)
 from repro.core import value as value_lib
 
 
@@ -132,24 +134,39 @@ def _tree_apply_delta(base, delta):
                       ).astype(b.dtype), base, delta)
 
 
-def _compressed_upload(codec, ef, comm, base, client_tree, i, seed,
+@jax.jit
+def upload_reconstruction(base, brow, decoded):
+    """Row ``brow`` of the stacked ``base`` plus the decoded delta: the
+    model the server received."""
+    return _tree_apply_delta(stacked_index(base, brow), decoded)
+
+
+def _compressed_upload(codec, ef, base, brow, client, crow, i, seed,
                        obs=None, device=None):
-    """One client's compressed upload: encode codec(delta vs ``base``, the
-    model the client downloaded) with error feedback, account the wire
-    bytes, and return the reconstruction the server actually receives.
-    ``device`` (sharded client state) moves the delta, and so the
+    """One client's compressed upload: encode codec(delta vs row ``brow``
+    of ``base``, the model the client downloaded, from row ``crow`` of
+    the stacked ``client`` trees) with error feedback, and return (the
+    reconstruction the server actually receives, the wire).  Where the
+    codec has a device round trip, nothing here reads the device: the
+    caller reads the wires' bytes (``wire_nbytes``) once its decisions are
+    made.  ``device`` (sharded client state) moves the delta, and so the
     client's error-feedback residual, onto that one device first: a
     Pallas codec kernel cannot be partitioned across devices.
-    Under obs the encode+decode is a host-timed "encode" span tagged
-    with the codec and the payload's actual wire bytes."""
-    delta = _tree_delta(client_tree, base)
+    Under obs the codec's work is a host-timed "encode" span tagged
+    with the codec."""
+    update = RowDelta(base, brow, client, crow)
     if device is not None:
-        delta = jax.device_put(delta, device)
+        update = jax.device_put(update.tree(), device)
     with (obs.timed("encode", client=i, codec=codec.name)
           if obs is not None else nullcontext()):
-        payload, decoded = compress_update(codec, ef, i, delta, seed=seed)
-    comm.record_upload(1, nbytes=payload.nbytes)
-    return _tree_apply_delta(base, decoded)
+        wire, decoded = compress_update(codec, ef, i, update, seed=seed)
+    if device is not None:
+        # back beside the base: replicated over the devices that hold it
+        held = jax.tree.leaves(base)[0].sharding
+        decoded = jax.device_put(decoded, NamedSharding(held.mesh,
+                                                        PartitionSpec())
+                                 if hasattr(held, "mesh") else held)
+    return upload_reconstruction(base, brow, decoded), wire
 
 
 def _compressed_broadcast(bcodec, comm, params, n, seed, obs=None):
@@ -184,18 +201,21 @@ def _round_uploads(run_cfg, codec, ef, comm, base, stacked, mask, t,
             if obs is not None:
                 obs.upload(i, sim, nbytes=comm.model_bytes, codec=codec.name)
         return stacked
-    recon = []
+    base1 = jax.tree.map(lambda x: x[None], base)
+    recon, wires = [], []
     for i in sel:
-        b0 = comm.uplink_bytes
-        recon.append(_compressed_upload(codec, ef, comm, base,
-                                        stacked_index(stacked, i), i,
-                                        _enc_seed(run_cfg, t, i, _UPLOAD),
-                                        obs=obs))
+        r, wire = _compressed_upload(codec, ef, base1, 0, stacked, i, i,
+                                     _enc_seed(run_cfg, t, i, _UPLOAD),
+                                     obs=obs)
+        recon.append(r)
+        wires.append(wire)
+    for i, wire, nbytes in zip(sel, wires, wire_nbytes(wires)):
+        comm.record_upload(1, nbytes=nbytes)
         if up_acc is not None:
-            up_acc[i] += comm.uplink_bytes - b0
+            up_acc[i] += nbytes
         if obs is not None:
-            obs.upload(i, sim, nbytes=comm.uplink_bytes - b0,
-                       codec=codec.name)
+            obs.upload(i, sim, nbytes=nbytes, codec=codec.name,
+                       device_codec=isinstance(wire, DeviceWire))
     if sel:   # one scatter per leaf, not one stack copy per client
         stacked = tree_scatter(stacked, jnp.asarray(sel), tree_stack(recon))
     return stacked

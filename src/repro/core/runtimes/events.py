@@ -23,6 +23,7 @@ import numpy as np
 import repro.checkpoint.store as ck
 
 from repro.common.pytree import tree_bytes
+from repro.compress import DeviceWire
 from repro.core.metrics import CommStats, RoundRecord, RunResult
 from repro.core.runtimes.common import (_BROADCAST, _UPLOAD,
                                         _attach_sim_result,
@@ -186,21 +187,26 @@ def run_event_driven(run_cfg, *, init_params_fn, loss_fn, fed_data,
         upload = policy.decide(i, value, norm, thr)
 
         if upload:
-            p0 = comm.upload_payload_bytes
+            wire, nbytes = None, comm.model_bytes
             if codec.is_identity:
                 recon = newp
                 comm.record_upload(1)
             else:
                 # ship codec(delta vs the model this client downloaded);
-                # the server mixes the reconstruction it actually received
-                recon = _compressed_upload(
-                    codec, ef, comm, client_params[i], newp, i,
+                # the server mixes the reconstruction it actually received.
+                # Size-1 stacks and row 0: the batched engine's programs
+                # at max_batch=1, so both engines run the same code
+                recon, wire = _compressed_upload(
+                    codec, ef, one, 0, newp_s, 0, i,
                     _enc_seed(run_cfg, ev, i, _UPLOAD), obs=obs)
+                nbytes = wire.nbytes
+                comm.record_upload(1, nbytes=nbytes)
             staleness = server_version - model_version[i]
             if obs is not None:
                 obs.upload(i, t_now, staleness=int(staleness),
-                           nbytes=comm.upload_payload_bytes - p0,
-                           codec=codec.name)
+                           nbytes=nbytes,
+                           codec=codec.name,
+                           device_codec=isinstance(wire, DeviceWire))
             s = aggregator.stale_weight(staleness)
             prev_prev_global = prev_global
             prev_global = global_params

@@ -55,6 +55,9 @@ class Observer:
         # name lookup (get-or-create) on every call
         m = self.metrics
         self._m_uploads = m.counter("uploads")
+        # of those, the uploads whose codec ran as one compiled device
+        # round trip (repro.compress, docs/COMPRESSION.md)
+        self._m_uploads_dev = m.counter("uploads_device_codec")
         self._m_upload_bytes = m.counter("upload_payload_bytes")
         self._m_staleness = m.hist("staleness")
         self._m_upload_nb = m.hist("upload_nbytes")
@@ -87,10 +90,13 @@ class Observer:
     # every hook: metrics always; trace record when tracing is on
 
     def upload(self, client, sim, *, staleness=0, nbytes=0,
-               codec="identity"):
+               codec="identity", device_codec=False):
         """One accepted model upload (sim = the event's completion time,
-        nbytes = actual on-the-wire payload bytes)."""
+        nbytes = actual on-the-wire payload bytes, device_codec = the
+        codec ran as its compiled device round trip)."""
         self._m_uploads.inc()
+        if device_codec:
+            self._m_uploads_dev.inc()
         self._m_upload_bytes.inc(nbytes)
         self._m_staleness.observe(staleness)
         self._m_upload_nb.observe(nbytes)

@@ -37,7 +37,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compress import ErrorFeedback, compress_update, get_codec
+from repro.compress import ErrorFeedback, compress_payload, get_codec
 from repro.core.runtimes.common import (_enc_seed, _event_helpers,
                                         _tree_delta, _value_fn, _UPLOAD)
 from repro.core.client import make_local_update
@@ -261,7 +261,7 @@ def _client_loop(compute: ClientCompute, channel, client: int, *,
                 # counter (the closed loop's global event counter doesn't
                 # exist under concurrency); deterministic per client
                 enc_seed = _enc_seed(seed_cfg, r, client, _UPLOAD)
-                payload, _ = compress_update(
+                payload, _ = compress_payload(
                     codec, ef, client, _tree_delta(newp, params),
                     seed=enc_seed)
             reply = _exchange(channel, UploadMsg(
@@ -426,7 +426,7 @@ class SequentialDriver:
                     # the GLOBAL event counter seeds the encoder — the
                     # bit-exactness hinge vs the closed loop
                     enc_seed = _enc_seed(cfg, ev, i, _UPLOAD)
-                    payload, _ = compress_update(
+                    payload, _ = compress_payload(
                         codec, ef, i, _tree_delta(newp, params[i]),
                         seed=enc_seed)
                 ch.send(UploadMsg(kind=wire.UPDATE, client=i, seq=seqs[i],

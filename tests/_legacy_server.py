@@ -20,7 +20,9 @@ import numpy as np
 
 from repro.common.pytree import (stacked_index, tree_bytes, tree_gather,
                                  tree_scatter, tree_stack, tree_sq_norm)
-from repro.compress import ErrorFeedback, compress_update, get_codec
+from repro.compress import ErrorFeedback, get_codec
+# the wire-plane codec path: the runtimes' device round trips match it
+from repro.compress import compress_payload as compress_update
 from repro.core import value as value_lib
 from repro.core.aggregation import (aggregate_or_keep, async_mix,
                                     buffered_coefs, buffered_mean,

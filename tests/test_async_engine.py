@@ -357,6 +357,84 @@ class TestEngineEquivalence:
         FLRunConfig(algorithm="vafl", engine="batched", shard_clients=True,
                     value_backend=lambda a, b: 0.0)
 
+# --------------------------------------------------- device codec path ---
+
+class TestDeviceCodecPath:
+    """The topk_int8 codec's compiled round trip: the batched engine
+    decides a window's uploads without reading the device, then reads
+    every upload's kept count in one copy, and counts the same bytes as
+    the Payload path."""
+
+    @pytest.mark.parametrize("buffer_size", [1, 3])
+    def test_decision_loop_reads_nothing_back(self, setup, monkeypatch,
+                                              buffer_size):
+        import contextlib
+
+        import repro.core.runtimes.batched as batched
+        from jax._src.array import ArrayImpl
+        from repro.compress import TopKQuantCodec
+        from repro.obs import Observer
+
+        kw = dict(comp="topk0.1_int8", buffer_size=buffer_size,
+                  max_batch=4, obs=True)
+        # the same run forced onto the Payload path
+        monkeypatch.setattr(TopKQuantCodec, "device_roundtrip", None)
+        plain = _run(setup, "vafl", "batched", **kw)
+        monkeypatch.undo()
+
+        # a device value read on the host inside "window.decide" counts as
+        # a read, except in the one read-back of the wires' bytes.  The
+        # transfer guard catches a copy where device memory is not host
+        # memory; on the CPU, the count of reads does
+        state = {"guard": False, "reads": 0, "readbacks": 0}
+        value = ArrayImpl._value
+
+        def counted(arr):
+            state["reads"] += state["guard"]
+            return value.fget(arr)
+        monkeypatch.setattr(ArrayImpl, "_value", property(counted))
+
+        span = Observer.span
+
+        @contextlib.contextmanager
+        def guarded_span(obs, name, **tags):
+            with span(obs, name, **tags):
+                if name != "window.decide":
+                    yield
+                    return
+                state["guard"] = True
+                try:
+                    with jax.transfer_guard_device_to_host("disallow"):
+                        yield
+                finally:
+                    state["guard"] = False
+        monkeypatch.setattr(Observer, "span", guarded_span)
+
+        wire_nbytes = batched.wire_nbytes
+
+        def readback(wires):
+            state["readbacks"] += 1
+            state["guard"] = False
+            try:
+                with jax.transfer_guard_device_to_host("allow"):
+                    return wire_nbytes(wires)
+            finally:
+                state["guard"] = True
+        monkeypatch.setattr(batched, "wire_nbytes", readback)
+
+        res = _run(setup, "vafl", "batched", **kw)
+        c = res.metrics["counters"]
+        assert state["reads"] == 0
+        assert state["readbacks"] == c["windows"] > 0
+        assert c["uploads_device_codec"] == c["uploads"] \
+            == res.comm.model_uploads > 0
+        assert plain.metrics["counters"].get("uploads_device_codec", 0) == 0
+        assert dataclasses.asdict(res.comm) == dataclasses.asdict(plain.comm)
+        assert res.byte_ccr == plain.byte_ccr > 0
+        assert [r.global_acc for r in res.records] == \
+            [r.global_acc for r in plain.records]
+
+
 # -------------------------------------------------- buffered aggregation ---
 
 def _rand_tree(key, scale=1.0):

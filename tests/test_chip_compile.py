@@ -77,6 +77,37 @@ def test_topk_quant_compiles_for_v5e(one_chip, width):
     _assert_kernel(topk_quant_2d.lower(x, f32, f32, seed, interpret=False))
 
 
+def test_topk_int8_roundtrip_compiles_for_v5e(one_chip, monkeypatch):
+    """The codec's device round trip for the CNN as the batched engine
+    calls it (rows of 7-client stacks, a residual): one program that
+    holds the kernel, under the instruction name by which a device trace
+    finds it (``topk_quant_2d``)."""
+    import re
+
+    import repro.kernels.topk_quant.kernel as kernel_mod
+    from repro.compress import RowDelta, composed
+    from repro.models.cnn import CNNConfig, cnn_init
+
+    # the kernel compiled, as on the chip (an explicit False compiles)
+    monkeypatch.setattr(kernel_mod, "interpret_mode", lambda i=None: False)
+    params = jax.eval_shape(lambda k: cnn_init(CNNConfig(), k),
+                            jax.random.key(0))
+    tree = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip), params)
+    stack = jax.tree.map(lambda a: _spec((7,) + a.shape, a.dtype, one_chip),
+                         params)
+    row = _spec((), jnp.int32, one_chip)
+    seed = _spec((), jnp.uint32, one_chip)
+    try:
+        text = composed.topk_int8_roundtrip.lower(
+            RowDelta(stack, row, stack, row), tree, seed, frac=0.1,
+            use_kernel=True).compile().as_text()
+    finally:
+        jax.clear_caches()   # no traced compiled kernel left for the CPU
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    assert re.match(r"\s*%topk_quant_2d(\.\d+)? = .*custom-call", calls[0])
+
+
 @pytest.mark.parametrize("width", list(WIDTHS))
 def test_grad_diff_norm_compiles_for_v5e(one_chip, width):
     x = _spec((_rows(WIDTHS[width]), LANE), jnp.float32, one_chip)

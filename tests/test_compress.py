@@ -198,6 +198,79 @@ class TestErrorFeedback:
         assert rel_err(ef.residuals[0], ef.residuals[1]) > 0.0
 
 
+# ------------------------------------------ device round trip vs Payload ---
+
+def _tied_tree(seed):
+    """Five magnitude levels: the k-th largest is tied with many others,
+    so more than k entries pass the threshold."""
+    levels = jnp.array([-1.0, -0.5, 0.25, 0.5, 1.0])
+    t = make_tree(seed)
+    return jax.tree.map(
+        lambda x: levels[jax.random.randint(key(seed + x.size), x.shape, 0,
+                                            5)], t)
+
+
+def _sparse_tree(seed):
+    """Mostly exact zeros: fewer than k nonzeros, so the threshold's clamp
+    drops the zeros and fewer than k entries survive."""
+    return jax.tree.map(
+        lambda x: jnp.where(jax.random.uniform(key(seed + x.size), x.shape)
+                            < 0.03, x, 0.0), make_tree(seed))
+
+
+TREES = {"normal": make_tree, "tied": _tied_tree, "sparse": _sparse_tree}
+
+
+class TestDeviceRoundTrip:
+    """The in-process runtimes' compiled round trip and the serve loop's
+    encode -> Payload -> decode give the same bits: reconstruction, new
+    error-feedback residual, and wire bytes."""
+
+    @pytest.mark.parametrize("use_kernel", [True, False],
+                             ids=["kernel", "oracle"])
+    @pytest.mark.parametrize("kind", list(TREES))
+    @pytest.mark.parametrize("seed", [0, 7, 2**31 + 11])
+    def test_matches_payload_path(self, seed, kind, use_kernel):
+        from repro.compress import compress_payload, wire_nbytes
+        from repro.core.runtimes.common import (_compressed_upload,
+                                                _tree_apply_delta,
+                                                _tree_delta)
+        codec = TopKQuantCodec(0.1, use_kernel=use_kernel)
+        base = make_tree(seed % 1000 + 100)   # the model downloaded
+        stack1 = lambda t: jax.tree.map(lambda x: x[None], t)  # noqa: E731
+        ef_p, ef_d = ErrorFeedback(), ErrorFeedback()
+        n = sum(x.size for x in jax.tree.leaves(base))
+        k = max(1, round(0.1 * n))
+        # the second transfer folds in the first one's residual
+        for step in range(2):
+            client = jax.tree.map(jnp.add, base, TREES[kind](seed + step))
+            enc_seed = seed + 1000 * step
+            payload, dec = compress_payload(codec, ef_p, 3,
+                                            _tree_delta(client, base),
+                                            seed=enc_seed)
+            recon, wire = _compressed_upload(codec, ef_d, stack1(base), 0,
+                                             stack1(client), 0, 3, enc_seed)
+            want = _tree_apply_delta(base, dec)
+            for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(recon)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            for a, b in zip(jax.tree.leaves(ef_p.residuals[3]),
+                            jax.tree.leaves(ef_d.residuals[3])):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            assert wire.nbytes == payload.nbytes
+            assert wire_nbytes([wire, payload]) == [payload.nbytes] * 2
+        kept = payload.planes["idx"].size
+        assert {"normal": kept >= k, "tied": kept > k,
+                "sparse": kept < k}[kind], (kept, k)
+
+    def test_codecs_without_a_round_trip_take_the_payload_path(self):
+        for spec in ("identity", "int8", "int4", "topk0.1"):
+            assert get_codec(spec).device_roundtrip is None
+        wire, _ = compress_update(TopKCodec(0.1), ErrorFeedback(), 0,
+                                  make_tree(), seed=1)
+        assert wire.planes["idx"].size == TopKCodec(0.1).k_of(
+            sum(x.size for x in jax.tree.leaves(make_tree())))
+
+
 # -------------------------------------------------- kernel vs ref parity ---
 
 class TestTopkQuantKernel:
@@ -250,16 +323,19 @@ class TestTopkQuantKernel:
             from repro.core.client import LocalSpec, make_weighted_classifier_loss
             from repro.data.partition import iid_partition
             from repro.data.synthetic import synthetic_mnist
-            from repro.kernels.topk_quant import ops
+            from repro.compress import composed
             from repro.models.cnn import MLPConfig, mlp_forward, mlp_init
 
+            # the codec's kernel runs inside its compiled round trip, on
+            # the devices that hold the program's operands
             spans = []
-            real = ops.topk_quant
+            real = composed.topk_int8_roundtrip
 
-            def spy(x2d, *a, **k):
-                spans.append(len(x2d.sharding.device_set))
-                return real(x2d, *a, **k)
-            ops.topk_quant = spy
+            def spy(tree, residual, *a, **k):
+                spans.append(len({d for x in jax.tree.leaves((tree, residual))
+                                  for d in x.sharding.device_set}))
+                return real(tree, residual, *a, **k)
+            composed.topk_int8_roundtrip = spy
 
             xtr, ytr, xte, yte = synthetic_mnist(8 * 40, 40, seed=0)
             mcfg = MLPConfig(hidden=(8,))
